@@ -12,9 +12,10 @@ length-prefixed localhost socket) and reports, per phase:
 * ``rt_ms``         — median control-frame round-trip latency,
 * ``bit_identical`` — vs the in-process Channel oracle.
 
-Falls back to the in-memory loopback transport (identical framing and
-byte accounting, no sockets) where process spawning is unavailable; the
-``mode`` field says which ran.
+The host party is a spawned process whose JAX runs on the CPU
+(``runtime/transport.py`` ``HOST_PLATFORM``), so it never races this
+process for an accelerator.  A socket run that cannot start raises: the
+rows always measure the socket transport.
 """
 
 from __future__ import annotations
@@ -51,17 +52,9 @@ def main(quick: bool = False):
     ref = VerticalBoosting(params).fit(Xg, y, [Xh])
 
     rows = []
-    run = None
+    run = MultiHostRun(params, [Xh], transport="socket",
+                       export_dir=tempfile.mkdtemp(), timeout=300.0)
     try:
-        try:
-            run = MultiHostRun(params, [Xh], transport="socket",
-                               export_dir=tempfile.mkdtemp(), timeout=300.0)
-            mode = "socket"
-        except Exception:                        # noqa: BLE001
-            run = MultiHostRun(params, [Xh], transport="loopback",
-                               export_dir=tempfile.mkdtemp())
-            mode = "loopback"
-
         # -- one training round (1 tree) over the transport -------------
         model, t_fit = timed(lambda: run.fit(Xg, y))
         train_tags = ("enc_gh", "assign_sync", "split_infos", "chosen_sid",
@@ -73,7 +66,7 @@ def main(quick: bool = False):
         rows.append((
             "transport/train_round",
             t_fit * 1e6,
-            f"mode={mode};ledger_bytes={ledger};socket_bytes={sock};"
+            f"mode=socket;ledger_bytes={ledger};socket_bytes={sock};"
             f"overhead_x={sock / max(ledger, 1):.2f};rt_ms={rt_ms:.3f};"
             f"roundtrips={model.stats.n_split_roundtrips};"
             f"bit_identical={ident}"))
@@ -97,13 +90,12 @@ def main(quick: bool = False):
         rows.append((
             "transport/serve_batch",
             t_serve * 1e6,
-            f"mode={mode};rows={n};ledger_bytes={ledger};"
+            f"mode=socket;rows={n};ledger_bytes={ledger};"
             f"socket_bytes={sock};overhead_x={sock / max(ledger, 1):.2f};"
             f"batch_ms={t_serve * 1e3:.1f};"
             f"bit_identical={bool(np.array_equal(score, s_ref))}"))
     finally:
-        if run is not None:
-            run.close()
+        run.close()
 
     rows += _bench_resilience(params, Xg, Xh, y, ref, quick)
     rows += _bench_trace_overhead(params, Xg, Xh, y, quick)
@@ -190,28 +182,22 @@ def _bench_resilience(params, Xg, Xh, y, ref, quick: bool):
         finally:
             run.close()
 
-    try:
-        t_plain, _, _ = one_fit(fault=False, resilient=False)
-        t_resil, ident, _ = one_fit(fault=False, resilient=True)
-        rows.append((
-            "transport/resilient_overhead",
-            t_resil * 1e6,
-            f"plain_us={t_plain * 1e6:.0f};"
-            f"overhead_pct={(t_resil / t_plain - 1) * 100:.1f};"
-            f"bit_identical={ident}"))
+    t_plain, _, _ = one_fit(fault=False, resilient=False)
+    t_resil, ident, _ = one_fit(fault=False, resilient=True)
+    rows.append((
+        "transport/resilient_overhead",
+        t_resil * 1e6,
+        f"plain_us={t_plain * 1e6:.0f};"
+        f"overhead_pct={(t_resil / t_plain - 1) * 100:.1f};"
+        f"bit_identical={ident}"))
 
-        t_crash, ident, restarts = one_fit(fault=True, resilient=True)
-        rows.append((
-            "transport/crash_recovery",
-            t_crash * 1e6,
-            f"faultfree_us={t_resil * 1e6:.0f};"
-            f"recovery_cost_us={(t_crash - t_resil) * 1e6:.0f};"
-            f"restarts={restarts};bit_identical={ident}"))
-    except Exception as e:                       # noqa: BLE001
-        # resilience rows need real process spawning; report instead of
-        # failing the whole benchmark where sockets are unavailable
-        rows.append(("transport/resilient_overhead", 0.0,
-                     f"skipped={type(e).__name__}"))
+    t_crash, ident, restarts = one_fit(fault=True, resilient=True)
+    rows.append((
+        "transport/crash_recovery",
+        t_crash * 1e6,
+        f"faultfree_us={t_resil * 1e6:.0f};"
+        f"recovery_cost_us={(t_crash - t_resil) * 1e6:.0f};"
+        f"restarts={restarts};bit_identical={ident}"))
     return rows
 
 

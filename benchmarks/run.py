@@ -83,6 +83,8 @@ def main() -> None:
                          "trace.json to this path")
     args = ap.parse_args()
     only = set(args.only.split(",")) if args.only else None
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
 
     tracer = None
     if args.trace:

@@ -14,6 +14,9 @@ import numpy as np
 
 from repro.core import LocalGBDT, SBTParams, VerticalBoosting
 from repro.data import synthetic_tabular
+from repro.launch.cache import use_compile_cache
+
+use_compile_cache()
 
 
 def auc(p, y):

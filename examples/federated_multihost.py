@@ -24,9 +24,11 @@ import numpy as np
 
 from repro.core import SBTParams, VerticalBoosting
 from repro.runtime.transport import MultiHostRun
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--loopback", action="store_true",
                     help="in-memory transport (same framing, no processes)")

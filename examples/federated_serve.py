@@ -23,9 +23,11 @@ import numpy as np
 
 from repro.core import SBTParams, VerticalBoosting
 from repro.serving import FederatedPredictor, export_model, load_ensemble
+from repro.launch.cache import use_compile_cache
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--out", default=None,
                     help="export directory (default: a temp dir)")

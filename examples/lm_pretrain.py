@@ -14,6 +14,7 @@ import dataclasses
 import jax
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.launch.train import train
 from repro.models.common import ModelConfig
 
@@ -26,6 +27,7 @@ CFG_100M = ModelConfig(
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--steps", type=int, default=300)
     ap.add_argument("--tiny", action="store_true",

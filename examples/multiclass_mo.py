@@ -13,6 +13,9 @@ sys.path.insert(0, "src")
 
 from repro.core import SBTParams, VerticalBoosting
 from repro.data import synthetic_tabular
+from repro.launch.cache import use_compile_cache
+
+use_compile_cache()
 
 k = 7
 X, y = synthetic_tabular(n=5000, d=20, seed=2, task="multi", n_classes=k)

@@ -15,6 +15,9 @@ import numpy as np
 
 from repro.core import SBTParams, VerticalBoosting
 from repro.data import synthetic_tabular
+from repro.launch.cache import use_compile_cache
+
+use_compile_cache()
 
 X, y = synthetic_tabular(n=4000, d=10, seed=0)
 X_guest, X_host = X[:, :5], X[:, 5:]

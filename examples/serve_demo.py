@@ -16,10 +16,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.cache import use_compile_cache
 from repro.models import LM
 
 
 def main():
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3_1_7b")
     ap.add_argument("--batch", type=int, default=4)

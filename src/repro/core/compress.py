@@ -92,7 +92,6 @@ def _sharded_compress(cipher, groups, eta_s: int, b_slot: int, mesh):
         return None
     import jax
     import jax.numpy as jnp
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from ..parallel.sharding import data_pad, gbdt_sharding
@@ -112,11 +111,11 @@ def _sharded_compress(cipher, groups, eta_s: int, b_slot: int, mesh):
             acc = cipher.add(acc, xs[:, s, :])
         return acc
 
-    out = shard_map(shard, mesh=mesh, in_specs=P("data", None, None),
-                    out_specs=P("data", None), check_rep=False)(x)
-    # land on one device before the decrypt consumer (jax-0.4.37 eager-
-    # mixing caveat, see kernels/histogram/ops.py)
-    return jax.device_put(out[:G], jax.devices()[0])
+    out = jax.shard_map(shard, mesh=mesh, in_specs=P("data", None, None),
+                        out_specs=P("data", None))(x)
+    # land on the mesh's first device next to the decrypt consumer's
+    # single-device operands
+    return jax.device_put(out[:G], mesh.devices.flat[0])
 
 
 def decompress_ints(plain_ints, sizes, eta_s: int, b_slot: int,

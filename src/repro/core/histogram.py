@@ -577,7 +577,7 @@ class CipherHistogram:
         if self._mesh_devices() > 1:
             # cts live mesh-sharded; land the small per-node totals next to
             # the (single-device) gathered histograms before mixing
-            tot_lazy = jax.device_put(tot_lazy, jax.devices()[0])
+            tot_lazy = jax.device_put(tot_lazy, self.mesh.devices.flat[0])
         node_total = self.cipher.reduce(tot_lazy[:n_d])   # (n_d, slots, L)
         nz = self.cipher.reduce(
             limbs.pad_limbs(hist, width).sum(axis=2))     # (n_d, n_f, s, L)
@@ -654,7 +654,6 @@ class CipherHistogram:
             return None
         import jax
         import jax.numpy as jnp
-        from jax.experimental.shard_map import shard_map
         from jax.sharding import PartitionSpec as P
 
         from ..kernels.modmul.modmul import BLOCK_N
@@ -675,15 +674,14 @@ class CipherHistogram:
             x = jnp.pad(x, [(0, bucket - G)] + [(0, 0)] * (x.ndim - 1))
         x = jax.device_put(
             x, gbdt_sharding(mesh, "split_infos", ndim=x.ndim))
-        out = shard_map(
+        out = jax.shard_map(
             lambda xs: self.cipher.reduce(jnp.cumsum(xs, axis=1)),
             mesh=mesh,
             in_specs=P("data", None, None, None),
-            out_specs=P("data", None, None, None),
-            check_rep=False)(x)
-        # land on one device (jax-0.4.37 eager-mixing caveat, see
-        # kernels/histogram/ops.py) before the shuffle/compress consumers
-        out = jax.device_put(out[:G], jax.devices()[0])
+            out_specs=P("data", None, None, None))(x)
+        # land on the mesh's first device, next to the single-device
+        # operands of the shuffle/compress consumers
+        out = jax.device_put(out[:G], mesh.devices.flat[0])
         # reduce canonicalizes the limb axis (hist width -> Ln)
         return out.reshape(lead + tuple(out.shape[1:]))
 

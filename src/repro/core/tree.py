@@ -547,10 +547,6 @@ def _encrypt_all(ctx: TreeContext, g_sel: np.ndarray,
             else:
                 cts = limbs.pad_limbs(ctx.cipher.encrypt_limbs(plain_dev),
                                       width)
-            # re-commit with the identical at-rest sharding (no data
-            # movement): a plain GSPMD array sidesteps the §7 eager-op
-            # caveat for partially-replicated shard_map outputs
-            cts = jax.device_put(cts, gbdt_sharding(mesh, "gh_cts"))
         elif ctx.cipher.name == "affine" and p.use_pallas:
             cts = encrypt_batch(ctx.cipher, plain.reshape(n * s, Lp),
                                 out_width=width).reshape(n, s, width)

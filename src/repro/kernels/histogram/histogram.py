@@ -14,6 +14,17 @@ per bin, not once per add -- see DESIGN.md §3).
 
 Grid: (feature_blocks, instance_blocks); instance axis is the innermost
 reduction axis, revisiting the same output block.
+
+Layout: the TPU lowering takes a block only when its last two dims are
+multiples of (8, 128) or equal the array's own dims.  A ``(block_i,
+block_f)`` tile of an ``(n_i, n_f)`` bins matrix breaks that rule once
+``n_f > block_f``, so the wrappers carry bins instance-minor, ``(n_f,
+n_i)``, tiled ``(block_f, block_i)``, and node slots as ``(1, n_i)`` (forest
+member slots as ``(k, 1, n_i)``) tiled ``(1, block_i)``.  That holds at any
+feature count and keeps the minor dim dense in HBM (an ``(n_i, 8)`` array
+pads its last dim to 128 lanes there).  Each tile's one-hot is then built
+column-major, ``(BF*C, BI)``, and contracts the instance axis as a plain
+``(BF*C, BI) @ (BI, L)`` MXU matmul.
 """
 
 from __future__ import annotations
@@ -24,32 +35,44 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from ..common import cdiv, default_interpret, round_up
+from ..common import default_interpret, round_up
 
-# VMEM budget at defaults (fp32): onehot 256x(8*32)=256KB, cts 256xLx4,
+# VMEM budget at defaults (fp32): onehot (8*32)x256=256KB, cts 256xLx4,
 # out 8x32xLx4 -- comfortably < 16MB for L <= 512.
 BLOCK_I = 256
 BLOCK_F = 8
-# layer-batched variant: onehot grows to 256x(BF*BN*n_b); at the defaults
+# layer-batched variant: onehot grows to (BF*BN*n_b)x256; at the defaults
 # (BF=8, BN=8, n_b=32) that is 2MB fp32, out block 8x8x32xLx4.
 BLOCK_N = 8
 
 
-def _hist_kernel(bins_ref, cts_ref, out_ref, *, n_bins: int):
-    i_blk = pl.program_id(1)
+def _onehot_accumulate(comp, cts_ref, out_ref, n_cols: int):
+    """out += onehot(comp) @ cts for one tile.
 
-    @pl.when(i_blk == 0)
+    comp: (BF, BI) int32 one-hot column per (feature, instance) in
+    [0, n_cols); negative = contributes nothing.  out_ref: a block whose
+    size is BF * n_cols * L."""
+    bf, bi = comp.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bf, n_cols, bi), 1)
+    oh = (comp[:, None, :] == cols).astype(jnp.float32)
+    oh = oh.reshape(bf * n_cols, bi)
+    cts = cts_ref[...].astype(jnp.float32)     # (BI, L)
+    part = jnp.dot(oh, cts, preferred_element_type=jnp.float32)
+    out_ref[...] += part.astype(jnp.int32).reshape(out_ref.shape)
+
+
+def _hist_kernel(bins_ref, cts_ref, out_ref, *, n_bins: int):
+    @pl.when(pl.program_id(1) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[...]                       # (BI, BF) int32
-    cts = cts_ref[...].astype(jnp.float32)     # (BI, L)
-    oh = (bins[:, :, None] == jnp.arange(n_bins)[None, None, :])
-    oh = oh.astype(jnp.float32).reshape(bins.shape[0], -1)   # (BI, BF*n_b)
-    # (BF*n_b, L) = oh^T @ cts  -- contract the instance axis on the MXU
-    part = jax.lax.dot_general(oh, cts, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    out_ref[...] += part.astype(jnp.int32).reshape(out_ref.shape)
+    _onehot_accumulate(bins_ref[...], cts_ref, out_ref, n_bins)
+
+
+def _pad_bins_t(bins, pi: int, pf: int):
+    """(n_i, n_f) bins -> (pf, pi) instance-minor, padding masked (-1)."""
+    n_i, n_f = bins.shape
+    return jnp.full((pf, pi), -1, jnp.int32).at[:n_f, :n_i].set(bins.T)
 
 
 @functools.partial(jax.jit, static_argnames=("n_bins", "interpret",
@@ -67,7 +90,7 @@ def hist_pallas(bins: jnp.ndarray, cts: jnp.ndarray, n_bins: int,
     n_i, n_f = bins.shape
     L = cts.shape[-1]
     pi, pf = round_up(max(n_i, 1), block_i), round_up(max(n_f, 1), block_f)
-    bins_p = jnp.full((pi, pf), -1, jnp.int32).at[:n_i, :n_f].set(bins)
+    bins_t = _pad_bins_t(bins, pi, pf)
     cts_p = jnp.zeros((pi, L), jnp.int32).at[:n_i].set(cts)
 
     grid = (pf // block_f, pi // block_i)
@@ -75,37 +98,32 @@ def hist_pallas(bins: jnp.ndarray, cts: jnp.ndarray, n_bins: int,
         functools.partial(_hist_kernel, n_bins=n_bins),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_i, block_f), lambda f, i: (i, f)),
+            pl.BlockSpec((block_f, block_i), lambda f, i: (f, i)),
             pl.BlockSpec((block_i, L), lambda f, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_f, n_bins, L), lambda f, i: (f, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((pf, n_bins, L), jnp.int32),
         interpret=interpret,
-    )(bins_p, cts_p)
+    )(bins_t, cts_p)
     return out[:n_f]
 
 
 def _layer_hist_kernel(bins_ref, node_ref, cts_ref, out_ref, *, n_bins: int,
-                       block_n: int):
-    n_blk = pl.program_id(0)
-    i_blk = pl.program_id(2)
+                       block_n: int, n_axis: int, i_axis: int):
+    # shared by the layer grid (node, feature, instance) and the forest grid
+    # (member, node, feature, instance): the member axis only selects which
+    # slot row the BlockSpec hands in, so the body is identical
+    n_blk = pl.program_id(n_axis)
 
-    @pl.when(i_blk == 0)
+    @pl.when(pl.program_id(i_axis) == 0)
     def _init():
         out_ref[...] = jnp.zeros_like(out_ref)
 
-    bins = bins_ref[...]                       # (BI, BF) int32
-    local = node_ref[...][:, 0] - n_blk * block_n   # (BI,) slot within block
-    in_blk = (local >= 0) & (local < block_n)
-    comp = jnp.where(in_blk[:, None] & (bins >= 0),
-                     local[:, None] * n_bins + bins, -1)
-    oh = (comp[:, :, None] == jnp.arange(block_n * n_bins)[None, None, :])
-    oh = oh.astype(jnp.float32).reshape(bins.shape[0], -1)  # (BI, BF*BN*n_b)
-    cts = cts_ref[...].astype(jnp.float32)     # (BI, L)
-    # (BF*BN*n_b, L) = oh^T @ cts  -- contract the instance axis on the MXU
-    part = jax.lax.dot_general(oh, cts, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    out_ref[...] += part.astype(jnp.int32).reshape(out_ref.shape)
+    bins = bins_ref[...]                       # (BF, BI) int32
+    local = node_ref[...] - n_blk * block_n    # (1, BI) slot within block
+    ok = (local >= 0) & (local < block_n) & (bins >= 0)
+    comp = jnp.where(ok, local * n_bins + bins, -1)
+    _onehot_accumulate(comp, cts_ref, out_ref, block_n * n_bins)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "interpret",
@@ -134,50 +152,26 @@ def layer_hist_pallas(bins: jnp.ndarray, node_slot: jnp.ndarray,
     pi = round_up(max(n_i, 1), block_i)
     pf = round_up(max(n_f, 1), block_f)
     pn = round_up(max(n_nodes, 1), block_n)
-    bins_p = jnp.full((pi, pf), -1, jnp.int32).at[:n_i, :n_f].set(bins)
-    node_p = jnp.full((pi, 1), -1, jnp.int32).at[:n_i, 0].set(node_slot)
+    bins_t = _pad_bins_t(bins, pi, pf)
+    node_p = jnp.full((1, pi), -1, jnp.int32).at[0, :n_i].set(node_slot)
     cts_p = jnp.zeros((pi, L), jnp.int32).at[:n_i].set(cts)
 
     grid = (pn // block_n, pf // block_f, pi // block_i)
     out = pl.pallas_call(
-        functools.partial(_layer_hist_kernel, n_bins=n_bins, block_n=block_n),
+        functools.partial(_layer_hist_kernel, n_bins=n_bins, block_n=block_n,
+                          n_axis=0, i_axis=2),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_i, block_f), lambda n, f, i: (i, f)),
-            pl.BlockSpec((block_i, 1), lambda n, f, i: (i, 0)),
+            pl.BlockSpec((block_f, block_i), lambda n, f, i: (f, i)),
+            pl.BlockSpec((1, block_i), lambda n, f, i: (0, i)),
             pl.BlockSpec((block_i, L), lambda n, f, i: (i, 0)),
         ],
         out_specs=pl.BlockSpec((block_f, block_n, n_bins, L),
                                lambda n, f, i: (f, n, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((pf, pn, n_bins, L), jnp.int32),
         interpret=interpret,
-    )(bins_p, node_p, cts_p)
+    )(bins_t, node_p, cts_p)
     return out[:n_f, :n_nodes].transpose(1, 0, 2, 3)
-
-
-def _forest_hist_kernel(bins_ref, slot_ref, cts_ref, out_ref, *, n_bins: int,
-                        block_n: int):
-    # grid (member, node_blocks, feature_blocks, instance_blocks); the
-    # member axis selects one column of the (n_i, k) slot matrix via the
-    # BlockSpec, so the body is the layer kernel verbatim.
-    n_blk = pl.program_id(1)
-    i_blk = pl.program_id(3)
-
-    @pl.when(i_blk == 0)
-    def _init():
-        out_ref[...] = jnp.zeros_like(out_ref)
-
-    bins = bins_ref[...]                       # (BI, BF) int32
-    local = slot_ref[...][:, 0] - n_blk * block_n   # (BI,) slot within block
-    in_blk = (local >= 0) & (local < block_n)
-    comp = jnp.where(in_blk[:, None] & (bins >= 0),
-                     local[:, None] * n_bins + bins, -1)
-    oh = (comp[:, :, None] == jnp.arange(block_n * n_bins)[None, None, :])
-    oh = oh.astype(jnp.float32).reshape(bins.shape[0], -1)  # (BI, BF*BN*n_b)
-    cts = cts_ref[...].astype(jnp.float32)     # (BI, L)
-    part = jax.lax.dot_general(oh, cts, (((0,), (0,)), ((), ())),
-                               preferred_element_type=jnp.float32)
-    out_ref[...] += part.astype(jnp.int32).reshape(out_ref.shape)
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "interpret",
@@ -191,8 +185,8 @@ def forest_hist_pallas(bins: jnp.ndarray, node_slot: jnp.ndarray,
 
     One launch accumulates every direct-mode frontier node of every member
     tree of a round-forest layer.  The grid gains a leading member axis; the
-    slot BlockSpec carves out member t's column of the (n_i, k) slot matrix,
-    and each (t, f, n) output block is visited contiguously over the
+    slot BlockSpec carves out member t's slot row of the (k, 1, n_i) slot
+    array, and each (t, f, n) output block is visited contiguously over the
     innermost instance axis.
 
     bins: (n_i, n_f) int32 (negative = masked), node_slot: (n_i, k) int32
@@ -209,23 +203,24 @@ def forest_hist_pallas(bins: jnp.ndarray, node_slot: jnp.ndarray,
     pi = round_up(max(n_i, 1), block_i)
     pf = round_up(max(n_f, 1), block_f)
     pn = round_up(max(n_nodes, 1), block_n)
-    bins_p = jnp.full((pi, pf), -1, jnp.int32).at[:n_i, :n_f].set(bins)
-    slot_p = jnp.full((pi, k), -1, jnp.int32).at[:n_i].set(node_slot)
+    bins_t = _pad_bins_t(bins, pi, pf)
+    slot_p = jnp.full((k, 1, pi), -1, jnp.int32).at[:, 0, :n_i].set(
+        node_slot.T)
     cts_p = jnp.zeros((pi, L), jnp.int32).at[:n_i].set(cts)
 
     grid = (k, pn // block_n, pf // block_f, pi // block_i)
     out = pl.pallas_call(
-        functools.partial(_forest_hist_kernel, n_bins=n_bins,
-                          block_n=block_n),
+        functools.partial(_layer_hist_kernel, n_bins=n_bins, block_n=block_n,
+                          n_axis=1, i_axis=3),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_i, block_f), lambda t, n, f, i: (i, f)),
-            pl.BlockSpec((block_i, 1), lambda t, n, f, i: (i, t)),
+            pl.BlockSpec((block_f, block_i), lambda t, n, f, i: (f, i)),
+            pl.BlockSpec((None, 1, block_i), lambda t, n, f, i: (t, 0, i)),
             pl.BlockSpec((block_i, L), lambda t, n, f, i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((1, block_f, block_n, n_bins, L),
+        out_specs=pl.BlockSpec((None, block_f, block_n, n_bins, L),
                                lambda t, n, f, i: (t, f, n, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((k, pf, pn, n_bins, L), jnp.int32),
         interpret=interpret,
-    )(bins_p, slot_p, cts_p)
+    )(bins_t, slot_p, cts_p)
     return out[:, :n_f, :n_nodes].transpose(0, 2, 1, 3, 4)

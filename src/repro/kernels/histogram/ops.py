@@ -17,7 +17,6 @@ import functools
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..common import cdiv, default_interpret, round_up
@@ -111,10 +110,12 @@ def _sharded_layer_hist(bins, node_slot, cts, n_nodes: int, n_bins: int,
         h = jax.lax.psum(h, "data")
         return jax.lax.all_gather(h, "model", axis=0, tiled=True)
 
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(P("data", None), P("data"), P("data", None)),
-                    out_specs=P(None, None, None, None),
-                    check_rep=False)(bins_p, slot_p, cts_p)
+    # check_vma=False: the per-shard Pallas call declares a plain output
+    # shape, and all_gather's replicated result is typed as varying
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P("data", None), P("data"), P("data", None)),
+                        out_specs=P(None, None, None, None),
+                        check_vma=False)(bins_p, slot_p, cts_p)
     return out[:n_nodes]
 
 
@@ -138,14 +139,11 @@ def sharded_layer_ciphertext_histogram(bins, node_slot, cts, n_nodes: int,
     cts = jnp.asarray(cts, jnp.int32)
     out = _sharded_layer_hist(bins, node_slot, cts, n_nodes, n_bins, mesh,
                               use_pallas, interpret)
-    # Land the gathered result on one device.  Downstream protocol steps
-    # (reduce / cumsum / shuffle) are small relative to accumulation and
-    # would otherwise execute redundantly on every replica; single-device
-    # placement also sidesteps a jax 0.4.37 CPU miscompile where eager ops
-    # mixing a partially-replicated shard_map output with unsharded operands
-    # sum the replicas (observed with jnp.concatenate: values silently
-    # multiply by the data-axis extent).
-    return jax.device_put(out, jax.devices()[0])
+    # Land the gathered result on the mesh's first device: downstream
+    # protocol steps (reduce / cumsum / shuffle) are small relative to
+    # accumulation and would otherwise run on every replica, and they mix
+    # the result with single-device arrays (cached parent histograms).
+    return jax.device_put(out, mesh.devices.flat[0])
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins", "mesh",
@@ -176,11 +174,12 @@ def _sharded_forest_hist(bins, node_slot, cts, n_nodes: int, n_bins: int,
         # (k, npm, n_f, n_b, L) local result)
         return jax.lax.all_gather(h, "model", axis=1, tiled=True)
 
-    out = shard_map(local, mesh=mesh,
-                    in_specs=(P("data", None), P("data", None),
-                              P("data", None)),
-                    out_specs=P(None, None, None, None, None),
-                    check_rep=False)(bins_p, slot_p, cts_p)
+    # check_vma=False: as in the layer variant
+    out = jax.shard_map(local, mesh=mesh,
+                        in_specs=(P("data", None), P("data", None),
+                                  P("data", None)),
+                        out_specs=P(None, None, None, None, None),
+                        check_vma=False)(bins_p, slot_p, cts_p)
     return out[:, :n_nodes]
 
 
@@ -193,8 +192,7 @@ def sharded_forest_ciphertext_histogram(bins, node_slot, cts, n_nodes: int,
     member axis rides along unchanged while instance tiles shard over "data"
     and member-local node blocks over "model".  Bit-identical to the
     single-device dispatch.  Returns the (k, n_nodes, n_f, n_bins, L) global
-    array landed on one device (same jax-0.4.37 workaround as the layer
-    variant)."""
+    array landed on the mesh's first device, like the layer variant."""
     if interpret is None:
         interpret = default_interpret()
     bins = jnp.asarray(bins, jnp.int32)
@@ -202,7 +200,7 @@ def sharded_forest_ciphertext_histogram(bins, node_slot, cts, n_nodes: int,
     cts = jnp.asarray(cts, jnp.int32)
     out = _sharded_forest_hist(bins, node_slot, cts, n_nodes, n_bins, mesh,
                                use_pallas, interpret)
-    return jax.device_put(out, jax.devices()[0])
+    return jax.device_put(out, mesh.devices.flat[0])
 
 
 @functools.partial(jax.jit, static_argnames=("n_nodes", "n_bins"))

@@ -20,7 +20,6 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ...analysis.registry import declassifies
@@ -68,11 +67,13 @@ def _sharded_modmul(x, T_b, n_l, T_mu, T_n, *, mesh, Ln: int,
         return r.reshape(xs.shape[:-1] + (r.shape[-1],))
 
     spec_x = P(*(("data",) + (None,) * (x.ndim - 1)))
-    return shard_map(
+    # check_vma=False: the per-shard Pallas calls declare plain output
+    # shapes, which the varying-axes check cannot type
+    return jax.shard_map(
         local, mesh=mesh,
         in_specs=(spec_x, P(None, None), P(None), P(None, None),
                   P(None, None)),
-        out_specs=spec_x, check_rep=False,
+        out_specs=spec_x, check_vma=False,
     )(x, T_b, n_l, T_mu, T_n)
 
 

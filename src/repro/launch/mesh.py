@@ -33,7 +33,11 @@ def make_gbdt_mesh(model: int | None = None):
     model = max(1, min(model, n))
     while n % model:
         model -= 1
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    # Auto axes: the engine places arrays with NamedShardings and lets the
+    # compiler propagate them; it does not carry shardings in its types
+    auto = jax.sharding.AxisType.Auto
+    return jax.make_mesh((n // model, model), ("data", "model"),
+                         axis_types=(auto, auto))
 
 
 def make_debug_mesh(*, multi_pod: bool = False):

@@ -1423,6 +1423,31 @@ class PartyProcess:
         return True
 
 
+# Spawned host parties run JAX on this platform.  The guest process holds
+# the accelerator (a chip belongs to one process at a time), so a child
+# left to pick its own backend would race the parent for it.  The platform
+# reaches the child through its environment, which it reads before it
+# imports JAX.
+HOST_PLATFORM = "cpu"
+_SPAWN_ENV_LOCK = threading.Lock()
+
+
+def _start_with_platform(proc, platform: str) -> None:
+    """Start a spawn-context process with ``JAX_PLATFORMS=platform`` in its
+    environment (the spawned interpreter copies ``os.environ`` at start);
+    the parent's own environment is restored before this returns."""
+    with _SPAWN_ENV_LOCK:
+        prev = os.environ.get("JAX_PLATFORMS")
+        os.environ["JAX_PLATFORMS"] = platform
+        try:
+            proc.start()
+        finally:
+            if prev is None:
+                del os.environ["JAX_PLATFORMS"]
+            else:
+                os.environ["JAX_PLATFORMS"] = prev
+
+
 def _wrap_fault(ep, fault_plan):
     if fault_plan is None:
         return ep
@@ -1442,6 +1467,9 @@ def host_main(port: int, hid: int, params, X_host,
     in-memory state (tables, ledger, seq counters) survives, and the
     guest's tree replay brings the protocol back in step.  Only a process
     death loses memory state, which is what ``state_dir`` is for."""
+    import jax
+    print(f"host{hid}: JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')} "
+          f"backend={jax.default_backend()}", flush=True)
     jitter = _random.Random((hid + 1) * 7919)
     pp = None
     channel = None
@@ -1504,11 +1532,12 @@ class MultiHostRun:
 
     ``transport="socket"`` spawns one OS process per host (multiprocessing
     ``spawn`` — a fresh interpreter, so jax state is never forked) talking
-    length-prefixed TCP on localhost.  ``transport="loopback"`` builds the
-    host PartyProcess objects in this process on in-memory endpoints with
-    the identical framing — same codec, same ledgers, no sockets — which
-    is what CI uses where spawning is too slow and what the benchmark
-    falls back to in sandboxes.
+    length-prefixed TCP on localhost; each child runs JAX on
+    ``HOST_PLATFORM``, never on the accelerator this process may hold.
+    ``transport="loopback"`` builds the host PartyProcess objects in this
+    process on in-memory endpoints with the identical framing — same codec,
+    same ledgers, no sockets — which is what CI uses where spawning is too
+    slow.
 
         run = MultiHostRun(params, [X_host])
         model = run.fit(X_guest, y)         # training over the transport
@@ -1614,7 +1643,7 @@ class MultiHostRun:
                   self.export_dir, self.state_dir, self.run_id, plan,
                   self.timeout),
             daemon=True)
-        p.start()
+        _start_with_platform(p, HOST_PLATFORM)
         return p
 
     def _accept_hosts(self, want: set, deadline_s: float) -> None:

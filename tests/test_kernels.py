@@ -14,7 +14,9 @@ from repro.kernels.modmul.ref import mul_fixed_ref
 from repro.kernels.modmul.modmul import mul_fixed_pallas
 
 HIST_SHAPES = [(64, 3, 8, 8), (300, 17, 33, 32), (257, 9, 130, 16),
-               (1024, 8, 20, 32), (1, 1, 4, 4)]
+               (1024, 8, 20, 32), (1, 1, 4, 4),
+               # feature counts the (8, 128) block rule once refused on TPU
+               (300, 14, 12, 32), (257, 33, 8, 16), (64, 1000, 4, 8)]
 
 
 @pytest.mark.parametrize("n_i,n_f,L,n_b", HIST_SHAPES)
@@ -36,7 +38,8 @@ def test_histogram_kernel_masked_all():
 
 
 @pytest.mark.parametrize("n_i,n_f,n_b", [(100, 4, 8), (1000, 33, 32),
-                                         (513, 7, 16), (2, 1, 4)])
+                                         (513, 7, 16), (2, 1, 4),
+                                         (700, 40, 32)])
 @pytest.mark.parametrize("dist", ["normal", "uniform", "sparse"])
 def test_binning_kernel_vs_ref(n_i, n_f, n_b, dist):
     rng = np.random.default_rng(n_i + n_b)

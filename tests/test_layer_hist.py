@@ -20,7 +20,10 @@ from repro.kernels.histogram import (hist_ref, layer_ciphertext_histogram,
 
 # shapes chosen to exercise non-divisible instance / feature / node blocks
 LAYER_SHAPES = [(300, 5, 16, 32, 3), (257, 9, 8, 16, 1), (64, 3, 4, 8, 9),
-                (1024, 17, 12, 32, 5), (1, 1, 4, 4, 2)]
+                (1024, 17, 12, 32, 5), (1, 1, 4, 4, 2),
+                # feature counts the (8, 128) block rule once refused on TPU
+                (300, 14, 16, 32, 3), (257, 33, 8, 16, 5),
+                (64, 1000, 4, 8, 2)]
 
 
 @pytest.mark.parametrize("n_i,n_f,L,n_b,n_n", LAYER_SHAPES)

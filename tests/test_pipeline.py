@@ -281,13 +281,16 @@ def test_forest_transport_bit_identical():
         run.close()
 
 
-def test_forest_kernel_matches_reference():
+@pytest.mark.parametrize("n_f,k", [(5, 3), (14, 1), (14, 4), (33, 1),
+                                   (33, 4), (1000, 1), (1000, 4)])
+def test_forest_kernel_matches_reference(n_f, k):
     """The (tree, node)-batched Pallas launch == the einsum reference on
-    random masked inputs."""
+    random masked inputs, at feature counts and member counts the TPU's
+    (8, 128) block rule once refused."""
     from repro.kernels.histogram import (forest_ciphertext_histogram,
                                          forest_hist_ref)
-    rng = np.random.default_rng(0)
-    n_i, n_f, n_b, k, n_nodes, L = 257, 5, 8, 3, 4, 6
+    rng = np.random.default_rng(n_f * 10 + k)
+    n_i, n_b, n_nodes, L = 257, 8, 4, 6
     bins = rng.integers(-1, n_b, (n_i, n_f)).astype(np.int32)
     slot = rng.integers(-1, n_nodes, (n_i, k)).astype(np.int32)
     cts = rng.integers(0, 256, (n_i, L)).astype(np.int32)

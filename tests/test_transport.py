@@ -10,13 +10,15 @@ localhost TCP.
 
 import os
 import tempfile
+from pathlib import Path
 
 import numpy as np
 import pytest
 
 from repro.core import SBTParams, VerticalBoosting
-from repro.runtime.transport import (KIND_CTRL, KIND_PROTO, LoopbackEndpoint,
-                                     MultiHostRun, TransportError,
+from repro.runtime.transport import (HOST_PLATFORM, KIND_CTRL, KIND_PROTO,
+                                     LoopbackEndpoint, MultiHostRun,
+                                     TransportError,
                                      decode_frame, decode_payload,
                                      encode_frame, encode_payload)
 
@@ -373,3 +375,42 @@ def test_socket_two_process_training_and_serving_bit_identical():
         assert merged.n_hist_launches == ref.stats.n_hist_launches
     finally:
         run.close()
+
+
+def test_socket_children_run_jax_on_the_stated_platform(capfd, monkeypatch):
+    """Spawned host parties run JAX on ``HOST_PLATFORM``, set in the child
+    before it imports JAX whatever this process's own setting, so a child
+    never races its parent for an accelerator; the parent's environment is
+    left as it was."""
+    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
+    X, _ = _data(n=64)
+    params = SBTParams(n_trees=1, max_depth=2, n_bins=8, cipher="plain")
+    run = MultiHostRun(params, [X[:, 3:]], transport="socket", timeout=300.0)
+    run.close()
+    assert "JAX_PLATFORMS" not in os.environ
+    assert (f"host0: JAX_PLATFORMS={HOST_PLATFORM} backend={HOST_PLATFORM}"
+            in capfd.readouterr().out)
+
+
+def test_bench_transport_raises_instead_of_falling_back(monkeypatch):
+    """A socket run that cannot start fails the transport benchmark: it
+    never quietly measures the in-memory loopback instead."""
+    monkeypatch.syspath_prepend(str(Path(__file__).resolve().parents[1]))
+    from benchmarks import bench_transport
+
+    class NoSpawn:
+        def __init__(self, *a, transport="socket", **k):
+            if transport != "loopback":
+                raise OSError("process spawning unavailable")
+
+    class Oracle:
+        def __init__(self, params):
+            pass
+
+        def fit(self, *a):
+            return self
+
+    monkeypatch.setattr(bench_transport, "MultiHostRun", NoSpawn)
+    monkeypatch.setattr(bench_transport, "VerticalBoosting", Oracle)
+    with pytest.raises(OSError, match="spawning unavailable"):
+        bench_transport.main(quick=True)
