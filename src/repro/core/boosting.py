@@ -223,15 +223,14 @@ class VerticalBoosting:
                 f"trees, model has {len(self.trees)} — rollback_to_round "
                 f"first")
         score = np.array(score, np.float64, copy=True)
-        y = self._y
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
+        # g/h are computed ONCE per round, for all classes (the paper's
+        # default multiclass setting): recomputing inside the class loop
+        # trained class c+1 on scores already updated by class c's tree
+        with self.tracer.span("grad", round=t, rows=len(score)):
+            g, h = self._loss.grad_hess(self._y, score)
         grown = []
         if p.objective == "multiclass":
-            # g/h are computed ONCE per round for all classes (the
-            # paper's default multiclass setting): recomputing inside
-            # the class loop trained class c+1 on scores already
-            # updated by class c's tree this round
-            g, h = self._loss.grad_hess(y, score)
             mix_party = self._mix_party(t, self._n_parties)
             ctxs, scheds = [], []
             for c in range(p.n_classes):
@@ -269,22 +268,24 @@ class VerticalBoosting:
             if pump is not None:      # defensive: last class never pumps
                 pump.join()
         elif p.forest_size > 1:
-            g, h = self._loss.grad_hess(y, score)
             grown.extend(self._grow_forest(self.cipher, g, h, t))
         else:
-            g, h = self._loss.grad_hess(y, score)
             tree, leaf_rows = self._grow(
                 self.cipher, g, h, t,
                 mix_party=self._mix_party(t, self._n_parties),
                 tree_idx=t)
             grown.append((tree, -1, leaf_rows))
-        for tree, cls, leaf_rows in grown:
-            self.trees.append(tree)
-            self.tree_class.append(cls)
-            self._apply(score, tree, leaf_rows, cls=cls)
-        dt = time.perf_counter() - t0
-        self.stats.tree_seconds.append(dt)
-        self.tracer.complete("round", int(t0 * 1e9), int(dt * 1e9), round=t)
+        with self.tracer.span("score_update", round=t, rows=len(score),
+                              trees=len(grown)):
+            for tree, cls, leaf_rows in grown:
+                self.trees.append(tree)
+                self.tree_class.append(cls)
+                self._apply(score, tree, leaf_rows, cls=cls)
+        dt_ns = time.perf_counter_ns() - t0
+        self.stats.tree_seconds.append(dt_ns * 1e-9)
+        # "round" is the benchmark harness's own annotation: a record in
+        # the ring only, never a profiler span (obs.trace.RESERVED)
+        self.tracer.complete("round", t0, dt_ns, round=t)
         return score
 
     def rollback_to_round(self, t: int) -> None:

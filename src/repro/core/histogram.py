@@ -645,7 +645,7 @@ class CipherHistogram:
         (accumulate and decrypt were sharded in PRs 2-3; the layer cumsum
         between them still serialized on one device).
 
-        Gated exactly like ``_decrypt_ints``: shard only when every data
+        Gated exactly like ``tree._decrypt``: shard only when every data
         shard gets at least one full kernel row block (shallow layers are
         sub-millisecond and would pay a shard_map compile per pow2 bucket).
         Returns None below the gate; the caller falls back to the

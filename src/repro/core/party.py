@@ -83,9 +83,7 @@ class Stats:
     #                       useful work by the pipelined prefetch pump
     #   guest_hist_seconds  guest plaintext candidate time overlapped
     #                       with in-flight host cipher work
-    #   host_dispatch_seconds  async launch of the host pipeline
     #   host_wait_seconds   blocking decrypt+decode tail
-    #   predict_seconds     serving engine wall time (bins->score)
     # _SERIES (list-backed):
     #   tree_seconds        per-tree wall time
     #   layer_overlap       per layer: guest-window / candidate-phase
@@ -94,8 +92,7 @@ class Stats:
     #   wire_overlap        per tree: fraction of the encrypt+ship window
     #                       that ran concurrently with other work
     _TIMERS = ("encrypt_seconds", "prefetch_seconds", "guest_hist_seconds",
-               "host_dispatch_seconds", "host_wait_seconds",
-               "predict_seconds")
+               "host_wait_seconds")
     _SERIES = ("tree_seconds", "layer_overlap", "wire_overlap")
 
     def __post_init__(self):

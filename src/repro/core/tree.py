@@ -282,11 +282,13 @@ class HostRuntime:
         the last block lands.  blk 0 is the replay anchor: a re-delivered
         sequence restarts assembly idempotently, matching the monolithic
         frame's re-delivery semantics."""
-        if "blk" in msg:
-            self._begin_tree_block(msg)
-            return
-        sel = np.asarray(msg["sel_rows"])
-        self._adopt_tree(msg, sel, msg["cts"])
+        with self.channel.tracer.span("host_begin_tree",
+                                      rows=len(msg["sel_rows"])):
+            if "blk" in msg:
+                self._begin_tree_block(msg)
+            else:
+                self._adopt_tree(msg, np.asarray(msg["sel_rows"]),
+                                 msg["cts"])
 
     def _begin_tree_block(self, msg: dict) -> None:
         b = int(msg["blk"])
@@ -347,7 +349,6 @@ class HostRuntime:
         while this work is in flight; across processes the overlap is
         physical."""
         p = self.params
-        t0_host = time.perf_counter()
         splittable = [int(nid) for nid in plan["splittable"]]
         forest = int(plan.get("forest", 0) or 0)
 
@@ -368,6 +369,14 @@ class HostRuntime:
                                              size)
         if not splittable:
             return
+        with self.channel.tracer.span("host_layer",
+                                      tree=int(plan.get("tree", -1)),
+                                      nodes=len(splittable)):
+            self._layer_batch(plan, splittable, forest)
+
+    def _layer_batch(self, plan: dict, splittable: list,
+                     forest: int) -> None:
+        p = self.params
         codec, cipher = self.codec, self.cipher
         engine = self.engine
         node_of = np.asarray(plan["node_of"])
@@ -454,10 +463,6 @@ class HostRuntime:
             nbytes = M * n_slots * ct_bytes + M * 8
             self.stats.n_packages += M * n_slots
         self._reply(wire.SPLIT_INFOS, payload, nbytes)
-        self.channel.tracer.complete(
-            "host_layer", int(t0_host * 1e9),
-            int((time.perf_counter() - t0_host) * 1e9),
-            tree=int(plan.get("tree", -1)), nodes=len(splittable))
 
     def on_chosen_sid(self, msg: dict) -> None:
         """The guest committed to one of this host's shuffled candidates:
@@ -524,44 +529,44 @@ def _encrypt_all(ctx: TreeContext, g_sel: np.ndarray,
     if blk:
         _encrypt_all_chunked(ctx, g_sel, h_sel, blk)
         return
-    t0 = time.perf_counter()
-    plain = ctx.codec.encode_plain(g_sel, h_sel)
-    n, s, Lp = plain.shape
-    if ctx.cipher.backend == "limb":
-        import jax
-        import jax.numpy as jnp
-        from ..kernels.modmul import encrypt_batch
-        width = ctx.cipher.hist_width
-        mesh = _crypto_mesh(p, ctx.cipher)
-        if mesh is not None:
-            from ..parallel.sharding import data_pad, gbdt_sharding
-            pad = data_pad(mesh, n)
-            if pad:     # pad rows encrypt 0 -> 0 and never receive a slot
-                plain = np.concatenate(
-                    [plain, np.zeros((pad, s, Lp), plain.dtype)], axis=0)
-            plain_dev = jax.device_put(jnp.asarray(plain, jnp.int32),
-                                       gbdt_sharding(mesh, "enc_plain"))
-            if ctx.cipher.name == "affine" and p.use_pallas:
-                cts = encrypt_batch(ctx.cipher, plain_dev, mesh=mesh,
-                                    out_width=width)
+    tr = ctx.channel.tracer
+    with tr.timed("encrypt", tree=int(ctx.tree_idx),
+                  rows=len(g_sel)) as enc:
+        with tr.span("encode", rows=len(g_sel)):
+            plain = ctx.codec.encode_plain(g_sel, h_sel)
+        n, s, Lp = plain.shape
+        if ctx.cipher.backend == "limb":
+            import jax
+            import jax.numpy as jnp
+            from ..kernels.modmul import encrypt_batch
+            width = ctx.cipher.hist_width
+            mesh = _crypto_mesh(p, ctx.cipher)
+            if mesh is not None:
+                from ..parallel.sharding import data_pad, gbdt_sharding
+                pad = data_pad(mesh, n)
+                if pad:   # pad rows encrypt 0 -> 0 and never receive a slot
+                    plain = np.concatenate(
+                        [plain, np.zeros((pad, s, Lp), plain.dtype)], axis=0)
+                plain_dev = jax.device_put(jnp.asarray(plain, jnp.int32),
+                                           gbdt_sharding(mesh, "enc_plain"))
+                if ctx.cipher.name == "affine" and p.use_pallas:
+                    cts = encrypt_batch(ctx.cipher, plain_dev, mesh=mesh,
+                                        out_width=width)
+                else:
+                    cts = limbs.pad_limbs(
+                        ctx.cipher.encrypt_limbs(plain_dev), width)
+            elif ctx.cipher.name == "affine" and p.use_pallas:
+                cts = encrypt_batch(ctx.cipher, plain.reshape(n * s, Lp),
+                                    out_width=width).reshape(n, s, width)
             else:
-                cts = limbs.pad_limbs(ctx.cipher.encrypt_limbs(plain_dev),
-                                      width)
-        elif ctx.cipher.name == "affine" and p.use_pallas:
-            cts = encrypt_batch(ctx.cipher, plain.reshape(n * s, Lp),
-                                out_width=width).reshape(n, s, width)
+                cts = limbs.pad_limbs(
+                    ctx.cipher.encrypt_limbs(jnp.asarray(plain)), width)
+            jax.block_until_ready(cts)
         else:
-            cts = limbs.pad_limbs(
-                ctx.cipher.encrypt_limbs(jnp.asarray(plain)), width)
-        jax.block_until_ready(cts)
-    else:
-        ints = limbs.to_pyints(plain.reshape(n * s, Lp))
-        cts = ctx.cipher.encrypt_ints(ints).reshape(n, s)
+            ints = limbs.to_pyints(plain.reshape(n * s, Lp))
+            cts = ctx.cipher.encrypt_ints(ints).reshape(n, s)
     ctx.stats.n_encrypt += n * s
-    dt = time.perf_counter() - t0
-    ctx.stats.encrypt_seconds += dt
-    ctx.channel.tracer.complete("encrypt", int(t0 * 1e9), int(dt * 1e9),
-                                tree=int(ctx.tree_idx), rows=int(n))
+    ctx.stats.encrypt_seconds += enc.seconds
     nbytes = n * s * ct_wire_bytes(ctx.cipher) + n * 4   # + selected row ids
     codec_view = {"n_slots": int(ctx.codec.n_slots),
                   "compressible": bool(ctx.codec.compressible),
@@ -619,24 +624,23 @@ def _encrypt_all_chunked(ctx: TreeContext, g_sel: np.ndarray,
     for host in ctx.hosts:
         host.bind(ctx.params, ctx.cipher, ctx.channel, ctx.stats)
     sel_rows = np.asarray(ctx.sel_rows)
+    tr = ctx.channel.tracer
     for b in range(n_blocks):
-        t0 = time.perf_counter()
         lo, hi = b * block, min((b + 1) * block, n)
-        plain = ctx.codec.encode_plain(g_sel[lo:hi], h_sel[lo:hi])
-        r, s, Lp = plain.shape
-        if ctx.cipher.name == "affine" and p.use_pallas:
-            cts = encrypt_batch(ctx.cipher, plain.reshape(r * s, Lp),
-                                out_width=Ln).reshape(r, s, Ln)
-        else:
-            cts = limbs.pad_limbs(
-                ctx.cipher.encrypt_limbs(jnp.asarray(plain)), Ln)
-        cts_u8 = np.asarray(jax.device_get(cts)).astype(np.uint8)
+        with tr.timed("encrypt_block", tree=int(ctx.tree_idx), blk=int(b),
+                      rows=hi - lo) as enc:
+            with tr.span("encode", rows=hi - lo):
+                plain = ctx.codec.encode_plain(g_sel[lo:hi], h_sel[lo:hi])
+            r, s, Lp = plain.shape
+            if ctx.cipher.name == "affine" and p.use_pallas:
+                cts = encrypt_batch(ctx.cipher, plain.reshape(r * s, Lp),
+                                    out_width=Ln).reshape(r, s, Ln)
+            else:
+                cts = limbs.pad_limbs(
+                    ctx.cipher.encrypt_limbs(jnp.asarray(plain)), Ln)
+            cts_u8 = np.asarray(jax.device_get(cts)).astype(np.uint8)
         ctx.stats.n_encrypt += r * s
-        dt = time.perf_counter() - t0
-        ctx.stats.encrypt_seconds += dt
-        ctx.channel.tracer.complete("encrypt_block", int(t0 * 1e9),
-                                    int(dt * 1e9), tree=int(ctx.tree_idx),
-                                    blk=int(b), rows=int(r))
+        ctx.stats.encrypt_seconds += enc.seconds
         ctx.stats.peak_block_bytes = max(
             ctx.stats.peak_block_bytes, int(cts_u8.nbytes) + r * 8)
         payload = {"tree": int(ctx.tree_idx), "seed": int(p.seed),
@@ -725,83 +729,124 @@ def _resolve_modes(splittable: list, hist_mode: dict, cache,
     return direct, subtract
 
 
-def _host_layer_finish(ctx: TreeContext, hid: int,
-                       splittable: list, pending: dict) -> dict:
+def _layer_candidates(ctx: TreeContext, depth: int, plan: dict | None,
+                      hosts: list, splittable: list,
+                      guest: Callable[[], dict]) -> tuple:
+    """One layer's candidate phase: every participating host gets the
+    layer ``plan`` (in-process, delivering it runs the host's
+    ``host_layer`` work, which leaves the cipher pipeline in flight on the
+    device), the guest computes its plaintext candidates (``guest()``)
+    meanwhile, then each host's ``split_infos`` reply is decrypted and
+    decoded.  The two sides are independent until ``find_best_split``
+    (DESIGN.md §8).  Returns ``(guest_cands, host_cands)``."""
+    def sync_plan():
+        for h in hosts:
+            ctx.channel.send("guest", f"host{h.hid}", wire.ASSIGN_SYNC,
+                             plan, plan["node_of"].size * 4)
+            h.deliver(wire.ASSIGN_SYNC, plan)
+
+    if not splittable:      # the plan still carries the hosts' eviction
+        sync_plan()
+        return {}, {}
+    tr = ctx.channel.tracer
+    tkw = dict(tree=int(ctx.tree_idx), depth=int(depth))
+    host_cands: dict = {}
+    with tr.timed("layer", nodes=len(splittable), **tkw) as layer:
+        sync_plan()
+        with tr.timed("guest_hist", **tkw) as gh:
+            guest_cands = guest()
+        with tr.timed("decrypt_wait", **tkw) as wait:
+            for h in hosts:
+                ctx.stats.n_split_roundtrips += 1
+                host_cands[h.hid] = _host_layer_finish(ctx, h, splittable)
+    if hosts:
+        ctx.stats.guest_hist_seconds += gh.seconds
+        ctx.stats.host_wait_seconds += wait.seconds
+        # overlap only exists for async-dispatch backends: the Paillier
+        # oracle completes synchronously inside the plan's delivery, so
+        # nothing is in flight while the guest works
+        if guest_cands and ctx.cipher.backend == "limb":
+            ctx.stats.layer_overlap.append(
+                gh.seconds / layer.seconds if layer.dur_ns > 0 else 0.0)
+    return guest_cands, host_cands
+
+
+def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
     """Guest side of the layer batch: ONE batched decrypt + decode
     (Algorithm 6) of the candidate stack a host answered ``assign_sync``
     with (``HostRuntime.on_assign_sync``).  In-process the stack is still
-    device-resident and the first ``np.asarray`` synchronizes the whole
+    device-resident and the decrypt's copy to numpy synchronizes the whole
     in-flight cipher pipeline; over the transport it arrives as a decoded
     limb tensor.  Returns {nid: SplitCandidates}."""
     limb = ctx.cipher.backend == "limb"
     n_slots = ctx.codec.n_slots
-    data, sizes, cl = pending["data"], pending["sizes"], pending["counts"]
-    m = int(pending["m"])
-    M = m * len(splittable)
-    use_compress = sizes is not None
-    if use_compress:
-        plain = _decrypt_ints(ctx, data)
-        ctx.stats.n_decrypt += len(plain)
-        vals = compress_mod.decompress_ints(
-            plain, sizes, ctx.codec.eta_s, ctx.codec.b_slot, padded=limb)
-        rows = np.asarray(vals, dtype=object).reshape(M, 1)
-    else:
-        if limb:
-            # keep the candidate stack on device into the batched decrypt
-            flat2 = data.reshape(M * n_slots, -1)
+    tr = ctx.channel.tracer
+    with tr.span("decrypt", tree=int(ctx.tree_idx), nodes=len(splittable)):
+        pending = host.collect(wire.SPLIT_INFOS)
+        data, sizes, cl = pending["data"], pending["sizes"], pending["counts"]
+        m = int(pending["m"])
+        M = m * len(splittable)
+        if sizes is None:
+            # keep a limb candidate stack on device into the decrypt
+            data = data.reshape((M * n_slots, -1) if limb else M * n_slots)
+        plain = _decrypt(ctx, data)
+    ctx.stats.n_decrypt += len(plain)
+    with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain)):
+        ints = limbs.to_pyints(plain) if limb else plain
+        if sizes is not None:
+            vals = compress_mod.decompress_ints(
+                ints, sizes, ctx.codec.eta_s, ctx.codec.b_slot, padded=limb)
+            rows = np.asarray(vals, dtype=object).reshape(M, 1)
         else:
-            flat2 = data.reshape(M * n_slots)
-        plain = _decrypt_ints(ctx, flat2)
-        ctx.stats.n_decrypt += M * n_slots
-        rows = np.asarray(plain, dtype=object).reshape(M, n_slots)
-    g_l, h_l = ctx.codec.decode(rows, cl)
+            rows = np.asarray(ints, dtype=object).reshape(M, n_slots)
+        g_l, h_l = ctx.codec.decode(rows, cl)
     out = {}
     for k, nid in enumerate(splittable):
         sl = slice(k * m, (k + 1) * m)
-        out[nid] = SplitCandidates(party=hid, sid=np.arange(m),
+        out[nid] = SplitCandidates(party=host.hid, sid=np.arange(m),
                                    g_l=g_l[sl], h_l=h_l[sl], cnt_l=cl[sl])
     return out
 
 
-def _decrypt_ints(ctx: TreeContext, cts) -> list:
-    if ctx.cipher.backend == "limb":
-        import jax.numpy as jnp
-        if ctx.cipher.name == "affine" and ctx.params.use_pallas:
-            from ..kernels.modmul import decrypt_batch
-            x = jnp.asarray(cts)
-            mesh = _crypto_mesh(ctx.params, ctx.cipher)
-            n = x.shape[0]
-            # shard only when every shard gets at least one full-size kernel
-            # row block: cipher-compressed package batches are small by
-            # design (that is the point of compression) and would pay a
-            # shard_map compile per pow2 bucket for sub-millisecond matmuls;
-            # large stacks (no-compress / MO / deep frontiers) shard for real
-            from ..kernels.modmul.modmul import BLOCK_N
-            dd = dict(mesh.shape).get("data", 1) if mesh is not None else 1
-            if mesh is not None and n >= BLOCK_N * dd:
-                import jax
+def _decrypt(ctx: TreeContext, cts):
+    """Plaintexts of a candidate stack: numpy limbs on the limb backends
+    (reading them back waits for the device work still in flight), python
+    ints on the Paillier oracle."""
+    if ctx.cipher.backend != "limb":
+        return ctx.cipher.decrypt_to_ints(cts)
+    import jax.numpy as jnp
+    x = jnp.asarray(cts)
+    if not (ctx.cipher.name == "affine" and ctx.params.use_pallas):
+        return np.asarray(ctx.cipher.decrypt_limbs(x))
+    from ..kernels.modmul import decrypt_batch
+    mesh = _crypto_mesh(ctx.params, ctx.cipher)
+    n = x.shape[0]
+    # shard only when every shard gets at least one full-size kernel
+    # row block: cipher-compressed package batches are small by
+    # design (that is the point of compression) and would pay a
+    # shard_map compile per pow2 bucket for sub-millisecond matmuls;
+    # large stacks (no-compress / MO / deep frontiers) shard for real
+    from ..kernels.modmul.modmul import BLOCK_N
+    dd = dict(mesh.shape).get("data", 1) if mesh is not None else 1
+    if mesh is not None and n >= BLOCK_N * dd:
+        import jax
 
-                from ..parallel.sharding import data_pad, gbdt_sharding
-                # the candidate stack is still device-resident: pad the
-                # candidate axis to the next power of two (the per-layer
-                # candidate count varies with the frontier, and the padded
-                # extent is a static shape — pow2 bucketing caps distinct
-                # compilations at O(log max_M), mirroring the node padding
-                # of the layer dispatch), then shard per the rule table and
-                # decrypt per shard with no collective
-                bucket = 1 << max(n - 1, 0).bit_length()
-                bucket += data_pad(mesh, bucket)
-                if bucket > n:
-                    x = jnp.pad(x, [(0, bucket - n)]
-                                + [(0, 0)] * (x.ndim - 1))
-                x = jax.device_put(
-                    x, gbdt_sharding(mesh, "split_infos", ndim=x.ndim))
-                pl_limbs = decrypt_batch(ctx.cipher, x, mesh=mesh)
-                return limbs.to_pyints(np.asarray(pl_limbs)[:n])
-            pl_limbs = decrypt_batch(ctx.cipher, x)
-            return limbs.to_pyints(np.asarray(pl_limbs))
-        return ctx.cipher.decrypt_to_ints(jnp.asarray(cts))
-    return ctx.cipher.decrypt_to_ints(cts)
+        from ..parallel.sharding import data_pad, gbdt_sharding
+        # the candidate stack is still device-resident: pad the
+        # candidate axis to the next power of two (the per-layer
+        # candidate count varies with the frontier, and the padded
+        # extent is a static shape — pow2 bucketing caps distinct
+        # compilations at O(log max_M), mirroring the node padding
+        # of the layer dispatch), then shard per the rule table and
+        # decrypt per shard with no collective
+        bucket = 1 << max(n - 1, 0).bit_length()
+        bucket += data_pad(mesh, bucket)
+        if bucket > n:
+            x = jnp.pad(x, [(0, bucket - n)] + [(0, 0)] * (x.ndim - 1))
+        x = jax.device_put(
+            x, gbdt_sharding(mesh, "split_infos", ndim=x.ndim))
+        return np.asarray(decrypt_batch(ctx.cipher, x, mesh=mesh))[:n]
+    return np.asarray(decrypt_batch(ctx.cipher, x))
 
 
 def _guest_layer_candidates(ctx: TreeContext, guest_frontier: GuestFrontier,
@@ -836,8 +881,14 @@ def grow_tree(ctx: TreeContext,
     boosting driver's score update — it is deliberately NOT attached to the
     :class:`FederatedTree`, so a model held for serving (or exported via
     ``serving/export.py``) carries no row-level training residue."""
+    with ctx.channel.tracer.span("tree", tree=int(ctx.tree_idx),
+                                 rows=len(ctx.sel_rows)):
+        return _grow_tree(ctx, feature_parties)
+
+
+def _grow_tree(ctx: TreeContext,
+               feature_parties: Callable[[int], tuple] | None) -> tuple:
     p = ctx.params
-    t_tree = time.perf_counter()
     if feature_parties is None:
         feature_parties = lambda d: (True, [h.hid for h in ctx.hosts])
 
@@ -896,13 +947,13 @@ def grow_tree(ctx: TreeContext,
         # plan (assignment vector + splittable batch + subtraction
         # schedule) is ONE serializable assign_sync message per host; each
         # host answers with ONE split_infos message.  In-process the
-        # deliver below runs the host pipeline as jax async dispatch; with
-        # remote hosts it is a no-op (the channel already shipped the
-        # plan) and every host process computes concurrently.  Either way
-        # the guest's plaintext numpy histograms run while the host cipher
-        # work is in flight, and only then does the guest block on the
-        # batched decrypt — the two sides are independent until
-        # find_best_split (DESIGN.md §8).
+        # delivery in _layer_candidates runs the host pipeline as jax async
+        # dispatch; with remote hosts it is a no-op (the channel already
+        # shipped the plan) and every host process computes concurrently.
+        # Either way the guest's plaintext numpy histograms run while the
+        # host cipher work is in flight, and only then does the guest
+        # block on the batched decrypt — the two sides are independent
+        # until find_best_split (DESIGN.md §8).
         # pipelined: the guest's plaintext layer candidates are the useful
         # work that hides the pump's encrypt + broadcast; compute them
         # BEFORE joining, then join so the assign_sync below never races
@@ -915,130 +966,105 @@ def grow_tree(ctx: TreeContext,
             pump.join()
             pump = None
 
-        guest_cands: dict = {}
-        host_cands: dict = {}
-        t0 = time.perf_counter()
+        plan = None
         if active_hosts:
             plan = {"tree": int(ctx.tree_idx),
                     "node_of": node_of,
                     "splittable": list(splittable),
                     "modes": [(nid,) + tuple(hist_mode[nid])
                               for nid in splittable]}
-            for h in active_hosts:
-                ctx.channel.send("guest", f"host{h.hid}", wire.ASSIGN_SYNC,
-                                 plan, node_of.size * 4)
-                h.deliver(wire.ASSIGN_SYNC, plan)
-        if splittable:
-            t1 = time.perf_counter()
+
+        def guest():
             if pre_cands is not None:
-                guest_cands = pre_cands
-            elif use_guest and ctx.guest_data.n_features > 0:
-                guest_cands = _guest_layer_candidates(
+                return pre_cands
+            if use_guest and ctx.guest_data.n_features > 0:
+                return _guest_layer_candidates(
                     ctx, guest_frontier, splittable, rows_sel, hist_mode)
-            t2 = time.perf_counter()
-            for h in active_hosts:
-                pend = h.collect(wire.SPLIT_INFOS)
-                ctx.stats.n_split_roundtrips += 1
-                host_cands[h.hid] = _host_layer_finish(ctx, h.hid,
-                                                       splittable, pend)
-            t3 = time.perf_counter()
-            if active_hosts:
-                ctx.stats.host_dispatch_seconds += t1 - t0
-                ctx.stats.guest_hist_seconds += t2 - t1
-                ctx.stats.host_wait_seconds += t3 - t2
-                # overlap only exists for async-dispatch backends: the
-                # Paillier oracle completes synchronously inside dispatch,
-                # so nothing is in flight while the guest works
-                if guest_cands and ctx.cipher.backend == "limb":
-                    denom = t3 - t0
-                    ctx.stats.layer_overlap.append(
-                        (t2 - t1) / denom if denom > 0 else 0.0)
-            tr = ctx.channel.tracer
-            if tr.enabled:
-                # re-emit the already-measured phase floats as spans:
-                # perf_counter() and perf_counter_ns() share one clock
-                tkw = dict(tree=int(ctx.tree_idx), depth=int(depth))
-                tr.complete("dispatch", int(t0 * 1e9), int((t1 - t0) * 1e9),
-                            **tkw)
-                tr.complete("guest_hist", int(t1 * 1e9),
-                            int((t2 - t1) * 1e9), **tkw)
-                tr.complete("decrypt_wait", int(t2 * 1e9),
-                            int((t3 - t2) * 1e9), **tkw)
-                tr.complete("layer", int(t0 * 1e9), int((t3 - t0) * 1e9),
-                            nodes=len(splittable), **tkw)
+            return {}
 
-        for nid in splittable:
-            node = nodes[nid]
-            rs = rows_sel[nid]
-            G_tot = g_sel[rs].sum(axis=0)
-            H_tot = h_sel[rs].sum(axis=0)
+        guest_cands, host_cands = _layer_candidates(
+            ctx, depth, plan, active_hosts, splittable, guest)
 
-            cands = []
-            if nid in guest_cands:
-                cands.append(guest_cands[nid])
-            for h in active_hosts:
-                cands.append(host_cands[h.hid][nid])
+        with ctx.channel.tracer.span("split", tree=int(ctx.tree_idx),
+                                     depth=int(depth),
+                                     nodes=len(splittable)):
+            for nid in splittable:
+                node = nodes[nid]
+                rs = rows_sel[nid]
+                G_tot = g_sel[rs].sum(axis=0)
+                H_tot = h_sel[rs].sum(axis=0)
 
-            best = find_best_split(cands, G_tot, H_tot, len(rs), p.lam,
-                                   p.min_leaf, p.min_gain)
-            if best is None:
-                node.weight = leaf_weight(G_tot, H_tot, p.lam, p.learning_rate)
-                continue
+                cands = []
+                if nid in guest_cands:
+                    cands.append(guest_cands[nid])
+                for h in active_hosts:
+                    cands.append(host_cands[h.hid][nid])
 
-            # resolve the split owner + instance assignment
-            ra = rows_all[nid]
-            fsel = ctx.sel_rows[rs]                 # full ids of selected rows
-            if best.party == GUEST:
-                fid, bid = decode_sid(best.sid, p.n_bins)
-                go_left = ctx.guest_data.bins[ra, fid] <= bid
-                go_left_sel = ctx.guest_data.bins[fsel, fid] <= bid
-                node.party, node.fid, node.bid = GUEST, fid, bid
-            else:
-                # the chosen split id travels WITH the node's instance
-                # space (the host resolves its private (fid, bid) and
-                # answers one go-left bitmask over those rows); the
-                # selected-row mask is derived guest-side — fsel is always
-                # a subset of the ascending ra, so no second message
-                host = next(h for h in ctx.hosts if h.hid == best.party)
-                msg = {"nid": nid, "sid": best.sid, "rows": ra}
-                ctx.channel.send("guest", f"host{host.hid}", wire.CHOSEN_SID,
-                                 msg, 8 + 4 * len(ra))
-                host.deliver(wire.CHOSEN_SID, msg)
-                go_left = np.asarray(host.collect(wire.ASSIGN_MASK), bool)
-                go_left_sel = go_left[np.searchsorted(ra, fsel)]
-                node.party, node.sid = host.hid, best.sid
-            node.gain = best.gain
+                best = find_best_split(cands, G_tot, H_tot, len(rs), p.lam,
+                                       p.min_leaf, p.min_gain)
+                if best is None:
+                    node.weight = leaf_weight(G_tot, H_tot, p.lam,
+                                              p.learning_rate)
+                    continue
 
-            lid, rid = len(nodes), len(nodes) + 1
-            node.left, node.right = lid, rid
-            rows_all[lid], rows_all[rid] = ra[go_left], ra[~go_left]
-            rows_sel[lid], rows_sel[rid] = rs[go_left_sel], rs[~go_left_sel]
-            nodes.append(Node(nid=lid, depth=depth + 1, n_rows=len(rows_all[lid])))
-            nodes.append(Node(nid=rid, depth=depth + 1, n_rows=len(rows_all[rid])))
-            # subtraction schedule: smaller child direct, sibling subtracts
-            if len(rows_sel[lid]) <= len(rows_sel[rid]):
-                hist_mode[lid] = ("direct", -1, -1)
-                hist_mode[rid] = ("subtract", nid, lid)
-            else:
-                hist_mode[rid] = ("direct", -1, -1)
-                hist_mode[lid] = ("subtract", nid, rid)
-            next_frontier += [lid, rid]
-        # free cached histograms: keep ONLY the parents the next layer's
-        # subtract-mode nodes will read.  Evicting just the used parents
-        # leaked every histogram cached for a node that became a leaf
-        # (triage, best=None, or max depth) — device memory grew with each
-        # dead branch for the tree's remainder.
-        keep = ({hist_mode[c][1] for c in next_frontier
-                 if hist_mode[c][0] == "subtract"}
-                if p.histogram_subtraction else set())
-        sizes = [guest_frontier.evict_except(keep)]
-        for h in ctx.hosts:
-            # remote handles hold no frontier: their PartyProcess evicts
-            # against the same schedule when the next assign_sync arrives
-            if getattr(h, "frontier", None) is not None:
-                sizes.append(h.frontier.evict_except(keep))
-        ctx.stats.peak_hist_cache = max(ctx.stats.peak_hist_cache,
-                                        max(sizes))
+                # resolve the split owner + instance assignment
+                ra = rows_all[nid]
+                fsel = ctx.sel_rows[rs]         # full ids of selected rows
+                if best.party == GUEST:
+                    fid, bid = decode_sid(best.sid, p.n_bins)
+                    go_left = ctx.guest_data.bins[ra, fid] <= bid
+                    go_left_sel = ctx.guest_data.bins[fsel, fid] <= bid
+                    node.party, node.fid, node.bid = GUEST, fid, bid
+                else:
+                    # the chosen split id travels WITH the node's instance
+                    # space (the host resolves its private (fid, bid) and
+                    # answers one go-left bitmask over those rows); the
+                    # selected-row mask is derived guest-side — fsel is
+                    # always a subset of the ascending ra, so no second
+                    # message
+                    host = next(h for h in ctx.hosts if h.hid == best.party)
+                    msg = {"nid": nid, "sid": best.sid, "rows": ra}
+                    ctx.channel.send("guest", f"host{host.hid}",
+                                     wire.CHOSEN_SID, msg, 8 + 4 * len(ra))
+                    host.deliver(wire.CHOSEN_SID, msg)
+                    go_left = np.asarray(host.collect(wire.ASSIGN_MASK), bool)
+                    go_left_sel = go_left[np.searchsorted(ra, fsel)]
+                    node.party, node.sid = host.hid, best.sid
+                node.gain = best.gain
+
+                lid, rid = len(nodes), len(nodes) + 1
+                node.left, node.right = lid, rid
+                rows_all[lid], rows_all[rid] = ra[go_left], ra[~go_left]
+                rows_sel[lid] = rs[go_left_sel]
+                rows_sel[rid] = rs[~go_left_sel]
+                nodes.append(Node(nid=lid, depth=depth + 1,
+                                  n_rows=len(rows_all[lid])))
+                nodes.append(Node(nid=rid, depth=depth + 1,
+                                  n_rows=len(rows_all[rid])))
+                # subtraction schedule: smaller child direct, sibling subtracts
+                if len(rows_sel[lid]) <= len(rows_sel[rid]):
+                    hist_mode[lid] = ("direct", -1, -1)
+                    hist_mode[rid] = ("subtract", nid, lid)
+                else:
+                    hist_mode[rid] = ("direct", -1, -1)
+                    hist_mode[lid] = ("subtract", nid, rid)
+                next_frontier += [lid, rid]
+            # free cached histograms: keep ONLY the parents the next layer's
+            # subtract-mode nodes will read.  Evicting just the used parents
+            # leaked every histogram cached for a node that became a leaf
+            # (triage, best=None, or max depth) — device memory grew with
+            # each dead branch for the tree's remainder.
+            keep = ({hist_mode[c][1] for c in next_frontier
+                     if hist_mode[c][0] == "subtract"}
+                    if p.histogram_subtraction else set())
+            sizes = [guest_frontier.evict_except(keep)]
+            for h in ctx.hosts:
+                # remote handles hold no frontier: their PartyProcess evicts
+                # against the same schedule when the next assign_sync arrives
+                if getattr(h, "frontier", None) is not None:
+                    sizes.append(h.frontier.evict_except(keep))
+            ctx.stats.peak_hist_cache = max(ctx.stats.peak_hist_cache,
+                                            max(sizes))
         ctx.stats.peak_frontier = max(ctx.stats.peak_frontier, len(frontier))
         frontier = next_frontier
 
@@ -1058,10 +1084,6 @@ def grow_tree(ctx: TreeContext,
     leaf_rows = {n.nid: rows_all[n.nid] for n in nodes if n.left == -1}
     tree = FederatedTree(nodes=nodes,
                          host_tables=[h.table for h in ctx.hosts])
-    ctx.channel.tracer.complete(
-        "tree", int(t_tree * 1e9),
-        int((time.perf_counter() - t_tree) * 1e9),
-        tree=int(ctx.tree_idx), n_nodes=len(nodes))
     return tree, leaf_rows
 
 
@@ -1154,117 +1176,92 @@ def grow_forest(ctx: TreeContext, bags: list,
             pump.join()
             pump = None
 
-        guest_cands: dict = {}
-        host_cands: dict = {}
-        t0 = time.perf_counter()
+        plan = None
         if active_hosts:
             plan = {"tree": int(ctx.tree_idx), "forest": k,
                     "node_of": node_of,
                     "splittable": list(splittable),
                     "modes": [(gid,) + tuple(hist_mode[gid])
                               for gid in splittable]}
-            for h in active_hosts:
-                ctx.channel.send("guest", f"host{h.hid}", wire.ASSIGN_SYNC,
-                                 plan, node_of.size * 4)
-                h.deliver(wire.ASSIGN_SYNC, plan)
-        if splittable:
-            t1 = time.perf_counter()
+
+        def guest():
             if pre_cands is not None:
-                guest_cands = pre_cands
-            elif use_guest and ctx.guest_data.n_features > 0:
-                guest_cands = _guest_layer_candidates(
+                return pre_cands
+            if use_guest and ctx.guest_data.n_features > 0:
+                return _guest_layer_candidates(
                     ctx, guest_frontier, splittable, rows_sel, hist_mode)
-            t2 = time.perf_counter()
-            for h in active_hosts:
-                pend = h.collect(wire.SPLIT_INFOS)
-                ctx.stats.n_split_roundtrips += 1
-                host_cands[h.hid] = _host_layer_finish(ctx, h.hid,
-                                                       splittable, pend)
-            t3 = time.perf_counter()
-            if active_hosts:
-                ctx.stats.host_dispatch_seconds += t1 - t0
-                ctx.stats.guest_hist_seconds += t2 - t1
-                ctx.stats.host_wait_seconds += t3 - t2
-                if guest_cands and ctx.cipher.backend == "limb":
-                    denom = t3 - t0
-                    ctx.stats.layer_overlap.append(
-                        (t2 - t1) / denom if denom > 0 else 0.0)
-            tr = ctx.channel.tracer
-            if tr.enabled:
-                tkw = dict(tree=int(ctx.tree_idx), depth=int(depth))
-                tr.complete("dispatch", int(t0 * 1e9), int((t1 - t0) * 1e9),
-                            **tkw)
-                tr.complete("guest_hist", int(t1 * 1e9),
-                            int((t2 - t1) * 1e9), **tkw)
-                tr.complete("decrypt_wait", int(t2 * 1e9),
-                            int((t3 - t2) * 1e9), **tkw)
-                tr.complete("layer", int(t0 * 1e9), int((t3 - t0) * 1e9),
-                            nodes=len(splittable), **tkw)
+            return {}
 
-        for gid in splittable:
-            m = gid // GID_STRIDE
-            node = nodes[m][gid % GID_STRIDE]
-            rs = rows_sel[gid]
-            G_tot = g_sel[rs].sum(axis=0)
-            H_tot = h_sel[rs].sum(axis=0)
+        guest_cands, host_cands = _layer_candidates(
+            ctx, depth, plan, active_hosts, splittable, guest)
 
-            cands = []
-            if gid in guest_cands:
-                cands.append(guest_cands[gid])
-            for h in active_hosts:
-                cands.append(host_cands[h.hid][gid])
+        with ctx.channel.tracer.span("split", tree=int(ctx.tree_idx),
+                                     depth=int(depth),
+                                     nodes=len(splittable)):
+            for gid in splittable:
+                m = gid // GID_STRIDE
+                node = nodes[m][gid % GID_STRIDE]
+                rs = rows_sel[gid]
+                G_tot = g_sel[rs].sum(axis=0)
+                H_tot = h_sel[rs].sum(axis=0)
 
-            best = find_best_split(cands, G_tot, H_tot, len(rs), p.lam,
-                                   p.min_leaf, p.min_gain)
-            if best is None:
-                node.weight = leaf_weight(G_tot, H_tot, p.lam,
-                                          p.learning_rate)
-                continue
+                cands = []
+                if gid in guest_cands:
+                    cands.append(guest_cands[gid])
+                for h in active_hosts:
+                    cands.append(host_cands[h.hid][gid])
 
-            ra = rows_all[gid]
-            fsel = ctx.sel_rows[rs]
-            if best.party == GUEST:
-                fid, bid = decode_sid(best.sid, p.n_bins)
-                go_left = ctx.guest_data.bins[ra, fid] <= bid
-                go_left_sel = ctx.guest_data.bins[fsel, fid] <= bid
-                node.party, node.fid, node.bid = GUEST, fid, bid
-            else:
-                host = next(h for h in ctx.hosts if h.hid == best.party)
-                msg = {"nid": gid, "sid": best.sid, "rows": ra}
-                ctx.channel.send("guest", f"host{host.hid}", wire.CHOSEN_SID,
-                                 msg, 8 + 4 * len(ra))
-                host.deliver(wire.CHOSEN_SID, msg)
-                go_left = np.asarray(host.collect(wire.ASSIGN_MASK), bool)
-                go_left_sel = go_left[np.searchsorted(ra, fsel)]
-                node.party, node.sid = host.hid, best.sid
-            node.gain = best.gain
+                best = find_best_split(cands, G_tot, H_tot, len(rs), p.lam,
+                                       p.min_leaf, p.min_gain)
+                if best is None:
+                    node.weight = leaf_weight(G_tot, H_tot, p.lam,
+                                              p.learning_rate)
+                    continue
 
-            lid, rid = len(nodes[m]), len(nodes[m]) + 1
-            gl, gr = m * GID_STRIDE + lid, m * GID_STRIDE + rid
-            node.left, node.right = lid, rid
-            rows_all[gl], rows_all[gr] = ra[go_left], ra[~go_left]
-            rows_sel[gl], rows_sel[gr] = rs[go_left_sel], rs[~go_left_sel]
-            nodes[m].append(Node(nid=lid, depth=depth + 1,
-                                 n_rows=len(rows_all[gl])))
-            nodes[m].append(Node(nid=rid, depth=depth + 1,
-                                 n_rows=len(rows_all[gr])))
-            if len(rows_sel[gl]) <= len(rows_sel[gr]):
-                hist_mode[gl] = ("direct", -1, -1)
-                hist_mode[gr] = ("subtract", gid, gl)
-            else:
-                hist_mode[gr] = ("direct", -1, -1)
-                hist_mode[gl] = ("subtract", gid, gr)
-            next_frontier += [gl, gr]
+                ra = rows_all[gid]
+                fsel = ctx.sel_rows[rs]
+                if best.party == GUEST:
+                    fid, bid = decode_sid(best.sid, p.n_bins)
+                    go_left = ctx.guest_data.bins[ra, fid] <= bid
+                    go_left_sel = ctx.guest_data.bins[fsel, fid] <= bid
+                    node.party, node.fid, node.bid = GUEST, fid, bid
+                else:
+                    host = next(h for h in ctx.hosts if h.hid == best.party)
+                    msg = {"nid": gid, "sid": best.sid, "rows": ra}
+                    ctx.channel.send("guest", f"host{host.hid}",
+                                     wire.CHOSEN_SID, msg, 8 + 4 * len(ra))
+                    host.deliver(wire.CHOSEN_SID, msg)
+                    go_left = np.asarray(host.collect(wire.ASSIGN_MASK), bool)
+                    go_left_sel = go_left[np.searchsorted(ra, fsel)]
+                    node.party, node.sid = host.hid, best.sid
+                node.gain = best.gain
 
-        keep = ({hist_mode[c][1] for c in next_frontier
-                 if hist_mode[c][0] == "subtract"}
-                if p.histogram_subtraction else set())
-        sizes = [guest_frontier.evict_except(keep)]
-        for h in ctx.hosts:
-            if getattr(h, "frontier", None) is not None:
-                sizes.append(h.frontier.evict_except(keep))
-        ctx.stats.peak_hist_cache = max(ctx.stats.peak_hist_cache,
-                                        max(sizes))
+                lid, rid = len(nodes[m]), len(nodes[m]) + 1
+                gl, gr = m * GID_STRIDE + lid, m * GID_STRIDE + rid
+                node.left, node.right = lid, rid
+                rows_all[gl], rows_all[gr] = ra[go_left], ra[~go_left]
+                rows_sel[gl], rows_sel[gr] = rs[go_left_sel], rs[~go_left_sel]
+                nodes[m].append(Node(nid=lid, depth=depth + 1,
+                                     n_rows=len(rows_all[gl])))
+                nodes[m].append(Node(nid=rid, depth=depth + 1,
+                                     n_rows=len(rows_all[gr])))
+                if len(rows_sel[gl]) <= len(rows_sel[gr]):
+                    hist_mode[gl] = ("direct", -1, -1)
+                    hist_mode[gr] = ("subtract", gid, gl)
+                else:
+                    hist_mode[gr] = ("direct", -1, -1)
+                    hist_mode[gl] = ("subtract", gid, gr)
+                next_frontier += [gl, gr]
+
+            keep = ({hist_mode[c][1] for c in next_frontier
+                     if hist_mode[c][0] == "subtract"}
+                    if p.histogram_subtraction else set())
+            sizes = [guest_frontier.evict_except(keep)]
+            for h in ctx.hosts:
+                if getattr(h, "frontier", None) is not None:
+                    sizes.append(h.frontier.evict_except(keep))
+            ctx.stats.peak_hist_cache = max(ctx.stats.peak_hist_cache,
+                                            max(sizes))
         ctx.stats.peak_frontier = max(ctx.stats.peak_frontier, len(frontier))
         frontier = next_frontier
 

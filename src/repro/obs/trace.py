@@ -8,11 +8,27 @@ and counts them, never blocks the hot path).  Timestamps come from
 float ``perf_counter()`` deltas can re-emit the interval exactly via
 :meth:`Tracer.complete` with ``int(t * 1e9)``.
 
+An enabled span also opens a ``jax.profiler.TraceAnnotation`` of the same
+name, so it lies on the host plane of any ``jax.profiler`` trace, on the
+profiler's clock, beside the device's operations.  The names in
+:data:`RESERVED` belong to a benchmark harness that pairs its own
+annotations of them with its own clock readings: :meth:`Tracer.span`
+refuses them, and the program records them with :meth:`Tracer.complete`,
+which opens no annotation.
+
 Zero-cost-when-disabled is the design contract: every emission site is
 guarded by ``tracer.enabled`` (one attribute load + bool test), and the
 module-level :data:`NULL_TRACER` singleton answers ``span()`` with a
 shared no-op context manager, so disabled tracing adds no allocation,
-no lock, no clock read.
+no lock, no clock read, no annotation.  :meth:`Tracer.timed` is the span
+of a phase that also feeds a ``Stats`` timer: disabled, it still reads
+the clock (the timer needs it) and records nothing.
+
+While an enabled tracer is the process default (:func:`set_default`),
+every garbage collection (``gc.callbacks``) and every executable JAX
+builds (the ``jax.monitoring`` duration event of a backend compile,
+ending when the event arrives) is recorded into it as a ``gc`` or
+``compile`` span, category ``process``.
 
 Event tuples are ``(ph, name, cat, ts_ns, dur_ns, tid, attrs)`` with
 ``ph`` the Chrome-trace phase ("X" complete, "i" instant).  ``attrs``
@@ -22,14 +38,23 @@ the wire over the ``trace_sync`` control tag.
 Categories in use: ``train`` (round/tree/layer/encrypt/...), ``wire``
 (one instant per :meth:`Channel.send` ledger append — the audited
 category), ``transport`` (framed ship/recv/broker/retry — physical, NOT
-audited, so the two views never double count), ``chaos``, ``serve``.
+audited, so the two views never double count), ``chaos``, ``serve``,
+``process`` (gc/compile).
 """
 
 from __future__ import annotations
 
+import gc
 import threading
 import time
 from collections import deque
+
+# Names a benchmark harness annotates itself (its window, its unit of work,
+# its reference check): the program never opens a profiler annotation
+# under them.
+RESERVED = frozenset({"window", "round", "serve_batch", "reference"})
+
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class _NullSpan:
@@ -46,10 +71,30 @@ class _NullSpan:
 _NULL_SPAN = _NullSpan()
 
 
-class _Span:
-    __slots__ = ("tracer", "name", "cat", "tid", "attrs", "start_ns")
+class _Timer:
+    """One clock-read pair; ``seconds`` after the block."""
+    __slots__ = ("start_ns", "dur_ns")
+
+    def __enter__(self):
+        self.start_ns = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, exc_type, exc, tb):
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        return False
+
+    @property
+    def seconds(self) -> float:
+        return self.dur_ns * 1e-9
+
+
+class _Span(_Timer):
+    __slots__ = ("tracer", "name", "cat", "tid", "attrs", "annotation")
 
     def __init__(self, tracer, name, cat, tid, attrs):
+        if name in RESERVED:
+            raise ValueError(f"span name {name!r} is reserved for the "
+                             f"benchmark harness; record it with complete()")
         self.tracer = tracer
         self.name = name
         self.cat = cat
@@ -57,15 +102,19 @@ class _Span:
         self.attrs = attrs
 
     def __enter__(self):
+        from jax.profiler import TraceAnnotation
+        self.annotation = TraceAnnotation(self.name)
+        self.annotation.__enter__()
         self.start_ns = time.perf_counter_ns()
         return self
 
     def __exit__(self, exc_type, exc, tb):
-        dur = time.perf_counter_ns() - self.start_ns
+        self.dur_ns = time.perf_counter_ns() - self.start_ns
+        self.annotation.__exit__(exc_type, exc, tb)
         if exc_type is not None:
             self.attrs["error"] = exc_type.__name__
-        self.tracer._emit("X", self.name, self.cat, self.start_ns, dur,
-                          self.tid, self.attrs)
+        self.tracer._emit("X", self.name, self.cat, self.start_ns,
+                          self.dur_ns, self.tid, self.attrs)
         return False
 
 
@@ -93,6 +142,13 @@ class Tracer:
         """``with tracer.span("layer", tree=t, depth=d): ...``"""
         if not self.enabled:
             return _NULL_SPAN
+        return _Span(self, name, cat, tid, attrs)
+
+    def timed(self, name: str, cat: str = "train", tid=None, **attrs):
+        """A span whose ``seconds`` feed a ``Stats`` timer: the same
+        clock-read pair times the phase whether or not it is recorded."""
+        if not self.enabled:
+            return _Timer()
         return _Span(self, name, cat, tid, attrs)
 
     def instant(self, name: str, cat: str = "train", tid=None, **attrs):
@@ -143,10 +199,81 @@ _default: Tracer = NULL_TRACER
 
 def set_default(tracer: Tracer) -> Tracer:
     global _default
+    _PROCESS_SPANS.flush()          # what the outgoing default is owed
     prev = _default
     _default = tracer if tracer is not None else NULL_TRACER
+    if _default.enabled:
+        _PROCESS_SPANS.install()
+    else:
+        _PROCESS_SPANS.remove()
     return prev
 
 
 def current() -> Tracer:
     return _default
+
+
+class _ProcessSpans:
+    """``gc`` and ``compile`` spans into the process-default tracer,
+    hooked in only while an enabled tracer is the default."""
+
+    def __init__(self):
+        self.installed = False
+        self._gc_open = None        # (start_ns, annotation) of a collection
+        self._owed: deque = deque()  # spans not yet in the default's ring
+
+    def install(self) -> None:
+        if self.installed:
+            return
+        import jax.monitoring
+        gc.callbacks.append(self.on_gc)
+        jax.monitoring.register_event_duration_secs_listener(self.on_compile)
+        self.installed = True
+
+    def remove(self) -> None:
+        if not self.installed:
+            return
+        import jax.monitoring
+        gc.callbacks.remove(self.on_gc)
+        jax.monitoring.unregister_event_duration_listener(self.on_compile)
+        self.installed = False
+        self._gc_open = None
+
+    def flush(self) -> None:
+        """Move the owed spans into the default's ring.  A collection can
+        run inside the tracer's own critical section, on the thread that
+        holds its lock (an allocation there triggers it): taking the lock
+        then would never return, so while it is held the spans stay owed
+        until the next chance."""
+        tr = _default
+        while self._owed and not tr._lock.locked():
+            name, start_ns, dur_ns, attrs = self._owed.popleft()
+            tr.complete(name, start_ns, dur_ns, cat="process", **attrs)
+
+    def on_gc(self, phase: str, info: dict) -> None:
+        # collections never overlap: the interpreter runs one at a time
+        if phase == "start":
+            from jax.profiler import TraceAnnotation
+            annotation = TraceAnnotation("gc")
+            annotation.__enter__()
+            self._gc_open = (time.perf_counter_ns(), annotation)
+        elif self._gc_open is not None:
+            start_ns, annotation = self._gc_open
+            self._gc_open = None
+            dur_ns = time.perf_counter_ns() - start_ns
+            annotation.__exit__(None, None, None)
+            self._owed.append(("gc", start_ns, dur_ns,
+                               {"generation": int(info["generation"]),
+                                "collected": int(info["collected"])}))
+            self.flush()
+
+    def on_compile(self, event: str, secs: float, **kw) -> None:
+        if event != COMPILE_EVENT:
+            return
+        dur_ns = int(secs * 1e9)
+        self._owed.append(("compile", time.perf_counter_ns() - dur_ns,
+                           dur_ns, {"fun": str(kw.get("fun_name", ""))}))
+        self.flush()
+
+
+_PROCESS_SPANS = _ProcessSpans()

@@ -215,7 +215,7 @@ class FederatedPredictor:
     def _predict_core(self, guest_bins: np.ndarray, host_parts: list,
                       binned: bool) -> np.ndarray:
         g = self.guest
-        t0 = time.perf_counter()
+        t0 = time.perf_counter_ns()
         if len(host_parts) != len(self.hosts):
             raise ValueError(f"expected {len(self.hosts)} host matrices, "
                              f"got {len(host_parts)}")
@@ -248,7 +248,55 @@ class FederatedPredictor:
         # reply is collected, so remote hosts compute their bit blocks
         # concurrently (latency = max over hosts, not the sum) — the same
         # dispatch-then-collect shape as the training layer batch.
-        t_rt = time.perf_counter()
+        with tracer.span("serve_roundtrip", cat="serve",
+                         hosts=len(self.hosts)):
+            blocks += self._host_bits(n, n_pad, host_parts, binned)
+
+        if blocks and g.depth > 0:
+            with tracer.span("serve_route", cat="serve", rows=int(n),
+                             trees=int(g.n_trees)):
+                bits = (blocks[0] if len(blocks) == 1
+                        else jnp.concatenate(blocks, axis=0))
+                node0 = jnp.broadcast_to(jnp.asarray(g.roots),
+                                         (n_pad, g.n_trees))
+                if self.mesh is not None:
+                    from ..parallel.sharding import gbdt_sharding
+                    bits = jax.device_put(
+                        bits, gbdt_sharding(self.mesh, "serve_bits"))
+                    node0 = jax.device_put(
+                        node0, gbdt_sharding(self.mesh, "serve_route"))
+                node = np.asarray(_route(bits, self._step, node0,
+                                         g.depth))[:n]
+        else:                               # every tree is a lone leaf
+            node = np.broadcast_to(g.roots, (n, g.n_trees))
+
+        # float accumulation replays the legacy per-tree order exactly
+        with tracer.span("serve_leaf_sum", cat="serve", rows=int(n),
+                         trees=int(g.n_trees)):
+            w = g.leaf_w[node]              # (n, n_trees, w_dim)
+            if g.objective == "binary":
+                score = np.full(n, g.init_score)
+                for t in range(g.n_trees):
+                    score += w[:, t, 0]
+            elif g.objective == "multiclass":
+                score = np.tile(g.init_score, (n, 1))
+                for t in range(g.n_trees):
+                    score[:, g.tree_class[t]] += w[:, t, 0]
+            else:                           # mo: vector leaves
+                score = np.tile(g.init_score, (n, 1))
+                for t in range(g.n_trees):
+                    score += w[:, t]
+        # "serve_batch" is the benchmark harness's own annotation: a
+        # record in the ring only (obs.trace.RESERVED)
+        tracer.complete("serve_batch", t0, time.perf_counter_ns() - t0,
+                        cat="serve", rows=int(n))
+        return score
+
+    def _host_bits(self, n: int, n_pad: int, host_parts: list,
+                   binned: bool) -> list:
+        """One round-trip per host: every request goes out, then every
+        reply (a packed bit block) is collected."""
+        blocks = []
         pending = []                        # (block slot, party, i)
         down: list = []                     # typed per-party failures
         # ONE request object for all hosts: the transport's broadcast
@@ -294,44 +342,4 @@ class FederatedPredictor:
             # was consumed: never a hang, never an answer scored from a
             # subset of the parties' bits
             raise down[0]
-        tracer.complete("serve_roundtrip", int(t_rt * 1e9),
-                        int((time.perf_counter() - t_rt) * 1e9),
-                        cat="serve", hosts=len(self.hosts))
-
-        if blocks and g.depth > 0:
-            with tracer.span("serve_route", cat="serve", rows=int(n),
-                             trees=int(g.n_trees)):
-                bits = (blocks[0] if len(blocks) == 1
-                        else jnp.concatenate(blocks, axis=0))
-                node0 = jnp.broadcast_to(jnp.asarray(g.roots),
-                                         (n_pad, g.n_trees))
-                if self.mesh is not None:
-                    from ..parallel.sharding import gbdt_sharding
-                    bits = jax.device_put(
-                        bits, gbdt_sharding(self.mesh, "serve_bits"))
-                    node0 = jax.device_put(
-                        node0, gbdt_sharding(self.mesh, "serve_route"))
-                node = np.asarray(_route(bits, self._step, node0,
-                                         g.depth))[:n]
-        else:                               # every tree is a lone leaf
-            node = np.broadcast_to(g.roots, (n, g.n_trees))
-
-        # float accumulation replays the legacy per-tree order exactly
-        w = g.leaf_w[node]                  # (n, n_trees, w_dim)
-        if g.objective == "binary":
-            score = np.full(n, g.init_score)
-            for t in range(g.n_trees):
-                score += w[:, t, 0]
-        elif g.objective == "multiclass":
-            score = np.tile(g.init_score, (n, 1))
-            for t in range(g.n_trees):
-                score[:, g.tree_class[t]] += w[:, t, 0]
-        else:                               # mo: vector leaves
-            score = np.tile(g.init_score, (n, 1))
-            for t in range(g.n_trees):
-                score += w[:, t]
-        dt = time.perf_counter() - t0
-        self.stats.predict_seconds += dt
-        tracer.complete("serve_batch", int(t0 * 1e9), int(dt * 1e9),
-                        cat="serve", rows=int(n))
-        return score
+        return blocks

@@ -459,3 +459,193 @@ def test_chaos_injected_fault_appears_as_trace_event():
         assert attrs["tag"] == "assign_sync" and attrs["count"] == 1
     finally:
         run.close()
+
+
+# ---------------------------------------------------------------------------
+# spans on the profiler's clock, where the round's time goes
+# ---------------------------------------------------------------------------
+
+# every phase span an in-process fit records
+FIT_PHASES = {"grad", "encrypt", "encode", "host_begin_tree", "layer",
+              "host_layer", "guest_hist", "decrypt_wait", "decrypt",
+              "decode", "split", "tree", "score_update"}
+
+
+def _host_event_names(trace_dir) -> set:
+    """Names of the events on the host planes of a ``jax.profiler``
+    trace written under ``trace_dir``."""
+    from pathlib import Path
+
+    from jax.profiler import ProfileData
+    path = sorted(Path(trace_dir).rglob("*.xplane.pb"))[-1]
+    profile = ProfileData.from_file(str(path))
+    return {e.name for plane in profile.planes
+            if plane.name.startswith("/host:")
+            for line in plane.lines for e in line.events}
+
+
+def _inside(child, parent) -> bool:
+    return (child[5] == parent[5] and child[3] >= parent[3]
+            and child[3] + child[4] <= parent[3] + parent[4])
+
+
+def test_enabled_span_is_on_the_profiler_host_plane(tmp_path):
+    import jax
+    on, off = Tracer("on"), Tracer("off", enabled=False)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with on.span("obs_probe_on"):
+            time.sleep(0.001)
+        with off.span("obs_probe_off"):
+            time.sleep(0.001)
+        with off.timed("obs_probe_timed_off") as timer:
+            time.sleep(0.001)
+    finally:
+        jax.profiler.stop_trace()
+    names = _host_event_names(tmp_path)
+    assert "obs_probe_on" in names
+    assert not names & {"obs_probe_off", "obs_probe_timed_off"}
+    assert [e[1] for e in on.export_events()] == ["obs_probe_on"]
+    # disabled: the shared no-op span, nothing recorded; a timed phase
+    # still measures what its Stats timer needs
+    assert off.span("x") is _NULL_SPAN and len(off) == 0
+    assert timer.seconds >= 0.001
+
+
+@pytest.mark.parametrize("name", ["window", "round", "serve_batch",
+                                  "reference"])
+def test_reserved_harness_names_are_refused_as_spans(name):
+    from repro.obs.trace import RESERVED
+    assert name in RESERVED
+    tr = Tracer("t")
+    with pytest.raises(ValueError):
+        tr.span(name)
+    with pytest.raises(ValueError):
+        tr.timed(name)
+    tr.complete(name, 0, 1)           # the ring record stays allowed
+    assert len(tr) == 1
+
+
+def test_inprocess_fit_records_every_phase_on_the_profiler(tmp_path):
+    """A tiny affine fit records each phase of the round, nested where
+    the work nests, and every span reaches the profiler's host plane
+    under its own name; none under a harness's reserved name."""
+    import jax
+    from repro.obs.trace import RESERVED
+    X, y = _data(n=300)
+    params = _fit_params(cipher="affine", key_bits=256, trace=True)
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        model = VerticalBoosting(params).fit(X[:, :3], y, [X[:, 3:]])
+    finally:
+        jax.profiler.stop_trace()
+    ev = [e for e in model.tracer.export_events() if e[0] == "X"]
+    names = {e[1] for e in ev}
+    assert FIT_PHASES <= names
+    assert "dispatch" not in names
+
+    def named(n):
+        return [e for e in ev if e[1] == n]
+    for child, parent in (("encode", "encrypt"), ("encrypt", "tree"),
+                          ("host_begin_tree", "tree"),
+                          ("host_layer", "layer"), ("guest_hist", "layer"),
+                          ("decrypt_wait", "layer"),
+                          ("decrypt", "decrypt_wait"),
+                          ("decode", "decrypt_wait"), ("layer", "tree"),
+                          ("split", "tree"), ("tree", "round"),
+                          ("grad", "round"), ("score_update", "round")):
+        for c in named(child):
+            assert any(_inside(c, p) for p in named(parent)), (child, parent)
+    # one decrypt and one decode per host per layer, one split per layer
+    assert len(named("decrypt")) == len(named("decode")) \
+        == len(named("layer")) == len(named("split"))
+    host = _host_event_names(tmp_path)
+    assert FIT_PHASES <= host
+    assert not host & RESERVED
+
+
+def test_serve_batch_records_leaf_sum():
+    X, y = _data(n=300)
+    model = VerticalBoosting(_fit_params(trace=True)).fit(
+        X[:, :3], y, [X[:, 3:]])
+    model.tracer.clear()
+    model.predict_proba(X[:, :3], [X[:, 3:]])
+    ev = [e for e in model.tracer.export_events() if e[0] == "X"]
+    names = [e[1] for e in ev]
+    assert {"serve_bins", "serve_roundtrip", "serve_route",
+            "serve_leaf_sum"} <= set(names)
+    (batch,) = [e for e in ev if e[1] == "serve_batch"]
+    (leaf_sum,) = [e for e in ev if e[1] == "serve_leaf_sum"]
+    assert _inside(leaf_sum, batch)
+    assert leaf_sum[6] == {"rows": 300, "trees": 2}
+
+
+def test_gc_and_compile_spans_only_under_an_enabled_default():
+    import gc
+
+    import jax
+    import jax.numpy as jnp
+    from repro.obs import trace as obs_trace
+    tr = Tracer("proc")
+    prev = obs_trace.set_default(tr)
+    try:
+        gc.collect()
+        salt = time.perf_counter_ns() % 1000003    # a fresh program
+        jax.jit(lambda x: x * 3 + salt)(jnp.arange(5)).block_until_ready()
+    finally:
+        obs_trace.set_default(prev)
+    ev = tr.export_events()
+    assert [e for e in ev if e[1] == "gc" and e[2] == "process"
+            and e[6]["generation"] == 2]
+    assert [e for e in ev if e[1] == "compile" and e[2] == "process"]
+    if not prev.enabled:          # the hooks went with the default
+        n = len(tr)
+        gc.collect()
+        salt += 1
+        jax.jit(lambda x: x * 5 + salt)(jnp.arange(5)).block_until_ready()
+        assert len(tr) == n
+        assert obs_trace._PROCESS_SPANS.on_gc not in gc.callbacks
+
+
+def test_gc_inside_the_tracers_critical_section_is_owed_not_deadlocked():
+    """A collection can start on the thread that holds the tracer's lock
+    (an allocation inside it triggers one): its span waits for the lock
+    to be free instead of taking it."""
+    import gc
+
+    from repro.obs import trace as obs_trace
+    tr = Tracer("proc")
+    prev = obs_trace.set_default(tr)
+    try:
+        with tr._lock:
+            gc.collect()
+        owed = len(tr)
+    finally:
+        obs_trace.set_default(prev)     # hands the owed spans over
+    assert owed == 0
+    assert [e for e in tr.export_events() if e[1] == "gc"]
+
+
+@pytest.mark.parametrize("kw", [
+    dict(cipher="affine", key_bits=256),
+    dict(cipher="affine", key_bits=256, row_block=128),
+    dict(cipher="plain", packing=False),
+], ids=["affine", "affine-streamed", "plain-nopack"])
+def test_inprocess_tracing_off_is_bit_identical(kw):
+    """Tracing on or off, in process: the same model, ledger and counts
+    (every new span site is observation only), and the disabled tracer
+    records nothing."""
+    X, y = _data(n=300)
+    Xg, Xh = X[:, :3], [X[:, 3:]]
+    off = VerticalBoosting(_fit_params(**kw)).fit(Xg, y, Xh)
+    on = VerticalBoosting(_fit_params(trace=True, **kw)).fit(Xg, y, Xh)
+    assert not off.tracer.enabled and len(off.tracer) == 0
+    np.testing.assert_array_equal(on.train_score_, off.train_score_)
+    assert on.channel.summary() == off.channel.summary()
+
+    def counts(m):
+        return {k: v for k, v in m.stats.as_dict().items()
+                if isinstance(v, int)}
+    assert counts(on) == counts(off)
+    np.testing.assert_array_equal(on.predict_proba(Xg, Xh),
+                                  off.predict_proba(Xg, Xh))
