@@ -132,55 +132,77 @@ def unpack_gh_int(x: int, plan: PackingPlan, sample_count: int) -> tuple:
     return g, h
 
 
-def unpack_gh_ints(xs, plan: PackingPlan, counts) -> tuple:
-    gs, hs = [], []
-    for x, c in zip(xs, counts):
-        g, h = unpack_gh_int(int(x), plan, int(c))
-        gs.append(g)
-        hs.append(h)
-    return np.asarray(gs, np.float64), np.asarray(hs, np.float64)
+# Word decode: the same sums from plaintext limbs, without python ints.
+# A field of up to 2 * 53 bits reads as two pieces that float64 holds
+# exactly; ``lo + hi * 2**53`` is then one IEEE rounding of the exact value,
+# which is what ``float(int)`` gives (nearest, ties to even).
+FIELD_BITS_MAX = 106
+_PIECE_BITS = 53
 
 
-def limbs_to_float64(arr: np.ndarray) -> np.ndarray:
-    """(..., L) limbs -> float64 value (rel. error <= 2**-52; fine for gains)."""
-    a = np.asarray(arr, dtype=np.float64)
-    w = 256.0 ** np.arange(a.shape[-1])
-    return a @ w
-
-
-def unpack_gh_limbs(arr: np.ndarray, plan: PackingPlan,
-                    counts: np.ndarray) -> tuple:
-    """Vectorized recovery from decrypted plaintext limbs (numpy, float64).
-
-    Used on the guest after decrypt for the limb backends; exactness within
-    float64 is sufficient for gain comparison (bit-exact path: python ints).
-    """
+def limb_top_bit(arr: np.ndarray) -> int:
+    """Bit length of the largest value in a (P, L) canonical limb array."""
     a = np.asarray(arr)
-    full, part = divmod(plan.b_h, limbs.RADIX_BITS)
-    # h = value mod 2**b_h
-    h_l = a.copy()
-    if part:
-        h_l[..., full] = a[..., full] & ((1 << part) - 1)
-        h_l[..., full + 1:] = 0
-    else:
-        h_l[..., full:] = 0
-    h = limbs_to_float64(h_l) / float(1 << plan.r)
-    # g = value >> b_h
-    g_l = _np_shift_right_bits(a, plan.b_h)
-    g = limbs_to_float64(g_l) / float(1 << plan.r)
-    g = g - plan.g_off * np.asarray(counts, np.float64)
+    cols = np.flatnonzero(a.any(axis=0))
+    if not cols.size:
+        return 0
+    j = int(cols[-1])
+    return limbs.RADIX_BITS * j + int(a[:, j].max()).bit_length()
+
+
+def limb_words(arr: np.ndarray, bits: int) -> np.ndarray:
+    """(P, L) canonical radix-2**8 limbs -> (W, P) uint64 words, word j of
+    every value in one contiguous row: the low ``bits`` bits of each
+    value, and a spare zero word so a field read never runs off the end."""
+    a = np.asarray(arr)
+    per_word = 64 // limbs.RADIX_BITS
+    W = bits // 64 + 2
+    take = min(a.shape[-1], W * per_word)
+    buf = np.zeros((a.shape[0], W * per_word), np.uint8)
+    buf[:, :take] = a[:, :take]
+    return np.ascontiguousarray(buf.view("<u8").T)
+
+
+def _word_bits(words: np.ndarray, off: np.ndarray, width: int) -> np.ndarray:
+    """Bits [off, off + width) of every value, width <= 64: (P, len(off))."""
+    k, s = np.divmod(off, 64)
+    s = s.astype(np.uint64)[:, None]
+    lo = words[k] >> s
+    # (w << 1) << (63 - s): the next word's share, with no shift by 64
+    hi = (words[k + 1] << np.uint64(1)) << (np.uint64(63) - s)
+    return ((lo | hi) & np.uint64((1 << width) - 1)).T
+
+
+def field_float64(words: np.ndarray, off, width: int) -> np.ndarray:
+    """``float(int)`` of the ``width``-bit field at bit ``off`` (an int
+    array of offsets) of every value in ``words``: (P, len(off)) float64."""
+    if width > FIELD_BITS_MAX:
+        raise ValueError(f"field of {width} bits > {FIELD_BITS_MAX}")
+    off = np.asarray(off, np.int64)
+    lo = _word_bits(words, off, min(width, _PIECE_BITS)).astype(np.float64)
+    if width <= _PIECE_BITS:
+        return lo
+    hi = _word_bits(words, off + _PIECE_BITS,
+                    width - _PIECE_BITS).astype(np.float64)
+    return lo + hi * 2.0 ** _PIECE_BITS
+
+
+def gh_fields(words: np.ndarray, off, plan: PackingPlan,
+              g_bits: int | None = None) -> tuple:
+    """``float`` of the g and h fields of the packed slot at bit ``off``:
+    h the low ``b_h`` bits, g the ``g_bits`` (default ``b_g``) above."""
+    off = np.asarray(off, np.int64)
+    h = field_float64(words, off, plan.b_h)
+    g = field_float64(words, off + plan.b_h,
+                      plan.b_g if g_bits is None else g_bits)
     return g, h
 
 
-def _np_shift_right_bits(a: np.ndarray, k: int) -> np.ndarray:
-    limb_shift, bit_shift = divmod(k, limbs.RADIX_BITS)
-    L = a.shape[-1]
-    x = np.zeros_like(a)
-    if limb_shift < L:
-        x[..., : L - limb_shift] = a[..., limb_shift:]
-    if bit_shift:
-        nxt = np.zeros_like(x)
-        nxt[..., :-1] = x[..., 1:]
-        x = (x >> bit_shift) | ((nxt << (limbs.RADIX_BITS - bit_shift))
-                                & limbs.LIMB_MASK)
-    return x
+def unpack_gh_floats(g_f: np.ndarray, h_f: np.ndarray, plan: PackingPlan,
+                     counts) -> tuple:
+    """:func:`unpack_gh_int`'s arithmetic on fields read by
+    :func:`gh_fields`, elementwise: the same float operations, so the same
+    bits."""
+    scale = float(1 << plan.r)
+    return (g_f / scale - plan.g_off * np.asarray(counts, np.float64),
+            h_f / scale)

@@ -90,6 +90,31 @@ class PackedCodec:
             g_l[i], h_l[i] = encoding.unpack_gh_int(int(row[0]), self.plan, int(c))
         return g_l, h_l
 
+    def decode_limbs(self, plain: np.ndarray, counts: np.ndarray, sizes):
+        """:meth:`decode` of the candidates in decrypted plaintext limbs
+        (packages when ``sizes`` is given), bit for bit, with no python
+        ints; None where a field is wider than the word reader takes."""
+        p = self.plan
+        if sizes is None:
+            # one package a candidate: g is every bit above h, as in
+            # ``x >> b_h``
+            g_bits = max(p.b_g, encoding.limb_top_bit(plain) - p.b_h)
+            if max(g_bits, p.b_h) > encoding.FIELD_BITS_MAX:
+                return None
+            words = encoding.limb_words(plain, p.b_h + g_bits)
+            g_f, h_f = encoding.gh_fields(words, [0], p, g_bits)
+            return encoding.unpack_gh_floats(g_f[:, 0], h_f[:, 0], p, counts)
+        if max(p.b_g, p.b_h) > encoding.FIELD_BITS_MAX:
+            return None
+        # ``decompress_ints(padded=True)``: eta_s slots a package, the
+        # first most significant; the last group's pad slots are dropped
+        slots = np.arange(self.eta_s)
+        words = encoding.limb_words(plain, self.eta_s * self.b_slot)
+        g_f, h_f = encoding.gh_fields(
+            words, (self.eta_s - 1 - slots) * self.b_slot, p)
+        keep = slots < np.asarray(sizes)[:, None]
+        return encoding.unpack_gh_floats(g_f[keep], h_f[keep], p, counts)
+
 
 class NoPackCodec:
     """Legacy SecureBoost: separate [[g]] and [[h]] ciphertexts."""
@@ -120,6 +145,16 @@ class NoPackCodec:
         h_l = np.asarray([int(r[1]) for r in ints], np.float64) / scale
         return g_l, h_l
 
+    def decode_limbs(self, plain: np.ndarray, counts: np.ndarray, sizes):
+        top = encoding.limb_top_bit(plain)
+        if top > encoding.FIELD_BITS_MAX:
+            return None
+        words = encoding.limb_words(plain, top)
+        f = encoding.field_float64(words, [0], top).reshape(-1, 2)
+        scale = float(1 << self.r)
+        return (f[:, 0] / scale - self.g_off * np.asarray(counts, np.float64),
+                f[:, 1] / scale)
+
 
 class MOCodec:
     """SecureBoost-MO: vector g/h packed across classes (Alg 7/8)."""
@@ -139,6 +174,23 @@ class MOCodec:
             g_l[i], h_l[i] = mo_encoding.unpack_gh_mo_ints(
                 [int(x) for x in row], self.plan, int(c))
         return g_l, h_l
+
+    def decode_limbs(self, plain: np.ndarray, counts: np.ndarray, sizes):
+        plan, base = self.plan, self.plan.base
+        if max(base.b_g, base.b_h) > encoding.FIELD_BITS_MAX:
+            return None
+        # ``unpack_gh_mo_ints``: a ciphertext's first class most significant
+        cts = plain.reshape(-1, plan.n_k, plain.shape[-1])
+        g_f, h_f = [], []
+        for k in range(plan.n_k):
+            used = plan.slots_in_ct(k)
+            words = encoding.limb_words(cts[:, k], used * base.b_gh)
+            g, h = encoding.gh_fields(
+                words, (used - 1 - np.arange(used)) * base.b_gh, base)
+            g_f.append(g); h_f.append(h)
+        return encoding.unpack_gh_floats(
+            np.concatenate(g_f, axis=1), np.concatenate(h_f, axis=1), base,
+            np.asarray(counts)[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -791,21 +843,36 @@ def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
             data = data.reshape((M * n_slots, -1) if limb else M * n_slots)
         plain = _decrypt(ctx, data)
     ctx.stats.n_decrypt += len(plain)
-    with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain)):
-        ints = limbs.to_pyints(plain) if limb else plain
-        if sizes is not None:
-            vals = compress_mod.decompress_ints(
-                ints, sizes, ctx.codec.eta_s, ctx.codec.b_slot, padded=limb)
-            rows = np.asarray(vals, dtype=object).reshape(M, 1)
+    with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain)) as dec:
+        gh = ctx.codec.decode_limbs(plain, cl, sizes) if limb else None
+        path = "pyints" if gh is None else "words"
+        if gh is None:      # the Paillier oracle, or a field too wide
+            gh = _decode_pyints(ctx.codec, plain, cl, sizes, limb)
+            ctx.stats.n_decode_pyints += M
         else:
-            rows = np.asarray(ints, dtype=object).reshape(M, n_slots)
-        g_l, h_l = ctx.codec.decode(rows, cl)
+            ctx.stats.n_decode_words += M
+        if tr.enabled:
+            dec.attrs["path"] = path
+        g_l, h_l = gh
     out = {}
     for k, nid in enumerate(splittable):
         sl = slice(k * m, (k + 1) * m)
         out[nid] = SplitCandidates(party=host.hid, sid=np.arange(m),
                                    g_l=g_l[sl], h_l=h_l[sl], cnt_l=cl[sl])
     return out
+
+
+def _decode_pyints(codec, plain, counts, sizes, limb: bool):
+    """The candidates' g/h sums through python ints: the Paillier oracle's
+    path, and the reference ``codec.decode_limbs`` matches bit for bit.
+    ``plain`` is limbs on the limb backends (short package groups padded
+    to ``eta_s`` slots there), python ints on the oracle."""
+    ints = limbs.to_pyints(plain) if limb else plain
+    if sizes is not None:
+        ints = compress_mod.decompress_ints(
+            ints, sizes, codec.eta_s, codec.b_slot, padded=limb)
+    rows = np.asarray(ints, dtype=object).reshape(len(counts), codec.n_slots)
+    return codec.decode(rows, counts)
 
 
 def _decrypt(ctx: TreeContext, cts):
