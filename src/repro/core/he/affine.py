@@ -118,7 +118,8 @@ class AffineCipher:
     def reduce(self, acc):
         """Reduce a lazy accumulator (sum of < 2**(8*headroom) ciphertexts).
         Limbs may be mixed-sign (lazy subtraction) as long as values >= 0."""
-        return limbs.barrett_reduce(limbs.carry_fix(acc), self.bctx)
+        return limbs.by_row_blocks(
+            lambda a: limbs.barrett_reduce(limbs.carry_fix(a), self.bctx), acc)
 
     def lazy_sub(self, parent, child_lazy, count_bound: int):
         """Histogram subtraction in the lazy limb domain: canonical parent

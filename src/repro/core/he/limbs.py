@@ -30,8 +30,35 @@ RADIX = 1 << RADIX_BITS
 LIMB_MASK = RADIX - 1
 
 
+# Rows of big integers one chain of limb programs takes at once.  A carry or
+# borrow fix-up holds about 4.4x its operand in temporaries on a TPU v5e, so
+# 2**19 rows of a Barrett product (2 Ln + 3 = 259 limbs at 1024 bits) hold
+# 2.4 GB; an MO layer's 1.57M candidate rows at once would need 7 GB beside
+# the layer's histograms.  Sized above every binary layer of the benchmark.
+ROW_BLOCK = 1 << 19
+
+
 def num_limbs_for_bits(bits: int) -> int:
     return -(-bits // RADIX_BITS)
+
+
+def by_row_blocks(fn, x, block: int | None = None):
+    """``fn(x)`` for a ``fn`` that treats each row of ``x`` (..., L) alone,
+    run over blocks of ``block`` rows (default :data:`ROW_BLOCK`) when
+    ``x`` holds more: the last block zero-padded to full size, so every
+    block compiles to one shape, and the pad rows' results dropped."""
+    block = ROW_BLOCK if block is None else block
+    lead = x.shape[:-1]
+    n = int(np.prod(lead))
+    if n <= block:
+        return fn(x)
+    flat = jnp.reshape(x, (n, x.shape[-1]))
+    pad = -n % block
+    if pad:
+        flat = jnp.pad(flat, ((0, pad), (0, 0)))
+    out = jnp.concatenate([fn(flat[i: i + block])
+                           for i in range(0, n + pad, block)])[:n]
+    return out.reshape(lead + out.shape[-1:])
 
 
 # ---------------------------------------------------------------------------

@@ -40,7 +40,10 @@ class MOPackingPlan:
 
     @property
     def limb_width(self) -> int:
-        return limbs.num_limbs_for_bits(self.eta_c * self.base.b_gh)
+        """Limbs of a packed plaintext: the cipher's plaintext width, not
+        ``eta_c * b_gh``, which moves with each round's g/h range and would
+        hand the device a new shape to compile mid-training."""
+        return limbs.num_limbs_for_bits(self.base.plaintext_bits)
 
 
 def plan_mo_packing(G: np.ndarray, H: np.ndarray, n_capacity: int,

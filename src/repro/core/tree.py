@@ -80,6 +80,7 @@ class PackedCodec:
         self.compressible = True
         self.b_slot = plan.b_gh
         self.eta_s = plan.compress_capacity
+        self.span_attrs = {}
 
     def encode_plain(self, g, h) -> np.ndarray:
         return encoding.pack_gh(g, h, self.plan)[:, None, :]   # (n, 1, Lp)
@@ -124,6 +125,7 @@ class NoPackCodec:
         self.g_off = g_off
         self.n_slots = 2
         self.compressible = False
+        self.span_attrs = {}
 
     @classmethod
     def plan(cls, g, r: int = encoding.DEFAULT_PRECISION):
@@ -163,6 +165,9 @@ class MOCodec:
         self.plan = plan
         self.n_slots = plan.n_k
         self.compressible = False    # paper §7.3.2: compress disabled for MO
+        # the packing the run chose (eqs 21-22), on the encode/decode spans
+        self.span_attrs = {"n_classes": plan.n_classes, "eta_c": plan.eta_c,
+                           "n_k": plan.n_k}
 
     def encode_plain(self, G, H) -> np.ndarray:
         return mo_encoding.pack_gh_mo(G, H, self.plan)          # (n, n_k, Lp)
@@ -584,7 +589,7 @@ def _encrypt_all(ctx: TreeContext, g_sel: np.ndarray,
     tr = ctx.channel.tracer
     with tr.timed("encrypt", tree=int(ctx.tree_idx),
                   rows=len(g_sel)) as enc:
-        with tr.span("encode", rows=len(g_sel)):
+        with tr.span("encode", rows=len(g_sel), **ctx.codec.span_attrs):
             plain = ctx.codec.encode_plain(g_sel, h_sel)
         n, s, Lp = plain.shape
         if ctx.cipher.backend == "limb":
@@ -681,7 +686,7 @@ def _encrypt_all_chunked(ctx: TreeContext, g_sel: np.ndarray,
         lo, hi = b * block, min((b + 1) * block, n)
         with tr.timed("encrypt_block", tree=int(ctx.tree_idx), blk=int(b),
                       rows=hi - lo) as enc:
-            with tr.span("encode", rows=hi - lo):
+            with tr.span("encode", rows=hi - lo, **ctx.codec.span_attrs):
                 plain = ctx.codec.encode_plain(g_sel[lo:hi], h_sel[lo:hi])
             r, s, Lp = plain.shape
             if ctx.cipher.name == "affine" and p.use_pallas:
@@ -833,7 +838,8 @@ def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
     limb = ctx.cipher.backend == "limb"
     n_slots = ctx.codec.n_slots
     tr = ctx.channel.tracer
-    with tr.span("decrypt", tree=int(ctx.tree_idx), nodes=len(splittable)):
+    with tr.span("decrypt", tree=int(ctx.tree_idx),
+                 nodes=len(splittable)) as dcr:
         pending = host.collect(wire.SPLIT_INFOS)
         data, sizes, cl = pending["data"], pending["sizes"], pending["counts"]
         m = int(pending["m"])
@@ -842,8 +848,11 @@ def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
             # keep a limb candidate stack on device into the decrypt
             data = data.reshape((M * n_slots, -1) if limb else M * n_slots)
         plain = _decrypt(ctx, data)
+        if tr.enabled:
+            dcr.attrs["cts"] = len(plain)
     ctx.stats.n_decrypt += len(plain)
-    with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain)) as dec:
+    with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain),
+                 **ctx.codec.span_attrs) as dec:
         gh = ctx.codec.decode_limbs(plain, cl, sizes) if limb else None
         path = "pyints" if gh is None else "words"
         if gh is None:      # the Paillier oracle, or a field too wide
@@ -913,7 +922,8 @@ def _decrypt(ctx: TreeContext, cts):
         x = jax.device_put(
             x, gbdt_sharding(mesh, "split_infos", ndim=x.ndim))
         return np.asarray(decrypt_batch(ctx.cipher, x, mesh=mesh))[:n]
-    return np.asarray(decrypt_batch(ctx.cipher, x))
+    return np.asarray(limbs.by_row_blocks(
+        lambda a: decrypt_batch(ctx.cipher, a), x))
 
 
 def _guest_layer_candidates(ctx: TreeContext, guest_frontier: GuestFrontier,
