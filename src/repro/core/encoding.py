@@ -5,7 +5,9 @@ A (g, h) pair is fixed-point encoded (eq 11), g offset to non-negative
 | h_int`` with bit budgets sized for the worst-case histogram sum over
 ``n_capacity`` instances (eqs 12-13).  Packing/unpacking is host-side numpy
 (runs once per boosting round); the packed plaintext then flows through the
-limb-based cipher backends.
+limb-based cipher backends.  The one device step is
+:func:`fields_float64_bits`, which reads decrypted fields as float64 bits
+where the plaintexts are, for the MO guest's candidates.
 
 Note: Algorithm 6 in the paper writes ``g = gh >> b_g`` -- that is a typo
 (the shift must be by ``b_h``, the width of the hessian field); we implement
@@ -17,7 +19,10 @@ from __future__ import annotations
 import dataclasses
 import math
 
+import jax
+import jax.numpy as jnp
 import numpy as np
+from jax import lax
 
 from .he import limbs
 
@@ -196,6 +201,96 @@ def gh_fields(words: np.ndarray, off, plan: PackingPlan,
     g = field_float64(words, off + plan.b_h,
                       plan.b_g if g_bits is None else g_bits)
     return g, h
+
+
+def _u32_at(lo, hi, s):
+    """32 bits at bit ``s`` (0-31) of the 64-bit ``hi:lo``; the next
+    word's share as ``(hi << 1) << (31 - s)``, with no shift by 32."""
+    return (lo >> s) | ((hi << 1) << (31 - s))
+
+
+def _u32_low_mask(n):
+    """The low ``n`` bits set, ``n`` clipped to 0-32."""
+    n = jnp.clip(n, 0, 32)
+    return jnp.where(n >= 32, jnp.uint32(0xFFFFFFFF),
+                     (jnp.uint32(1) << jnp.minimum(n, 31).astype(jnp.uint32))
+                     - 1)
+
+
+@jax.jit
+def fields_float64_bits(x, off, width):
+    """:func:`field_float64` on the device, as IEEE-754 binary64 bits.
+
+    ``x`` (B, L) canonical radix-2**8 limbs, one integer a row; ``off`` and
+    ``width`` (F,) int32, each field's bit offset and width (at most
+    :data:`FIELD_BITS_MAX`), traced, so fields that move or widen reuse the
+    executable.  Returns (B, 2F) uint32, the low then the high word of
+    ``float(field)`` for each field: nearest, ties to even, in 32-bit
+    integer ops (:func:`float64_of_bits` reads them on the host).
+    """
+    u32 = jnp.uint32
+    B, L = x.shape
+    W = -(-L // 4) + 4             # 32-bit words, and spare zero words
+    t = jnp.pad(x.T.astype(u32), ((0, 4 * W - L), (0, 0))).reshape(W, 4, B)
+    words = t[:, 0] | (t[:, 1] << 8) | (t[:, 2] << 16) | (t[:, 3] << 24)
+    off = off.astype(jnp.int32)
+    width = width.astype(jnp.int32)[:, None]
+    w = words[(off >> 5)[:, None] + jnp.arange(5)]          # (F, 5, B)
+    s = (off & 31).astype(u32)[:, None]
+    # the field as four words, least significant first
+    v = [_u32_at(w[:, j], w[:, j + 1], s) & _u32_low_mask(width - 32 * j)
+         for j in range(4)]
+    n = jnp.zeros(v[0].shape, jnp.int32)                     # bit length
+    for j, vj in enumerate(v):
+        n = jnp.where(vj != 0, 32 * (j + 1) - lax.clz(vj).astype(jnp.int32),
+                      n)
+    # the 53-bit significand is bits [n - 53, n) of the field: bits
+    # [o, o + 53) of ``field << 64``, o = n + 11 >= 12, so every read
+    # below is in range and a field of 53 bits or fewer comes out exact
+    z = jnp.zeros_like(v[0])
+    ext = [z, z] + v
+
+    def word(i):
+        out = z
+        for k in range(2, 6):
+            out = jnp.where(i == k, ext[k], out)
+        return out
+
+    o = n + 11
+    q, sh = o >> 5, (o & 31).astype(u32)
+    mid = word(q + 1)
+    m_lo = _u32_at(word(q), mid, sh)
+    m_hi = _u32_at(mid, word(q + 2), sh) & u32(0x1FFFFF)
+    r = o - 1                                                # rounding bit
+    half = ((word(r >> 5) >> (r & 31).astype(u32)) & 1) == 1
+    sticky = z != 0
+    for k in range(2, 6):
+        sticky |= (ext[k] & _u32_low_mask(r - 32 * k)) != 0
+    inc = (half & (sticky | ((m_lo & 1) == 1))).astype(u32)
+    m_lo = m_lo + inc
+    m_hi = m_hi + (inc & (m_lo == 0))
+    # a carry to 2**53 moves the exponent and leaves the fraction 0
+    e = (n + 1022).astype(u32) + (m_hi >> 21)
+    hi = jnp.where(n == 0, u32(0), (e << 20) | (m_hi & u32(0xFFFFF)))
+    lo = jnp.where(n == 0, u32(0), m_lo)
+    return jnp.stack([lo, hi], axis=1).reshape(2 * lo.shape[0], B).T
+
+
+@jax.jit
+def _word_pairs_by_field(bits):
+    """(B, 2F) word pairs -> (F, 2B): a field's doubles in one long row.
+    A device may lay a narrow (B, 2F) array out column-major, which no
+    float64 view can read; the long rows it keeps row-major."""
+    B, F2 = bits.shape
+    return bits.reshape(B, F2 // 2, 2).transpose(1, 0, 2).reshape(F2 // 2,
+                                                                   2 * B)
+
+
+def float64_of_bits(bits) -> np.ndarray:
+    """:func:`fields_float64_bits`' (B, 2F) words as (F, B) float64 on the
+    host: one copy of 8 bytes a field, read in place by a view."""
+    rows = np.ascontiguousarray(np.asarray(_word_pairs_by_field(bits)))
+    return rows.view(np.float64)
 
 
 def unpack_gh_floats(g_f: np.ndarray, h_f: np.ndarray, plan: PackingPlan,

@@ -53,8 +53,9 @@ class Stats:
     n_hom_scalar: int = 0       # scalar/shift multiplications (compress)
     n_split_infos: int = 0      # split-info stats produced (pre-compress)
     n_packages: int = 0         # ciphertexts actually decrypted/transferred
-    n_decode_words: int = 0     # candidates the guest decoded from limb
-                                # words (numpy) ...
+    n_decode_device: int = 0    # candidates the guest decoded on the
+                                # device (float64 bits), ...
+    n_decode_words: int = 0     # ... from limb words (numpy) ...
     n_decode_pyints: int = 0    # ... and through python ints
     n_hist_launches: int = 0    # histogram accumulation kernel launches
     n_split_roundtrips: int = 0  # guest<->host split_infos exchanges

@@ -82,6 +82,8 @@ class PackedCodec:
         self.eta_s = plan.compress_capacity
         self.span_attrs = {}
 
+    decode_device = None         # candidates decode from host words
+
     def encode_plain(self, g, h) -> np.ndarray:
         return encoding.pack_gh(g, h, self.plan)[:, None, :]   # (n, 1, Lp)
 
@@ -126,6 +128,8 @@ class NoPackCodec:
         self.n_slots = 2
         self.compressible = False
         self.span_attrs = {}
+
+    decode_device = None
 
     @classmethod
     def plan(cls, g, r: int = encoding.DEFAULT_PRECISION):
@@ -196,6 +200,32 @@ class MOCodec:
         return encoding.unpack_gh_floats(
             np.concatenate(g_f, axis=1), np.concatenate(h_f, axis=1), base,
             np.asarray(counts)[:, None])
+
+    def decode_device(self, plain, counts):
+        """:meth:`decode_limbs` of plaintext limbs still on the device, bit
+        for bit: every class's g and h field becomes float64 bits there
+        (``encoding.fields_float64_bits``, in row blocks), so 16 bytes a
+        class cross to the host, not the limbs; None where a field is wider
+        than the bit reader takes."""
+        plan, base = self.plan, self.plan.base
+        if max(base.b_g, base.b_h) > encoding.FIELD_BITS_MAX:
+            return None
+        M, l = len(counts), plan.n_classes
+        x = plain.reshape(M, -1)          # a candidate's n_k plaintexts a row
+        # ``unpack_gh_mo_ints``: class c in slot c % eta_c of ciphertext
+        # c // eta_c, a ciphertext's first class most significant
+        k, s = np.divmod(np.arange(l), plan.eta_c)
+        used = np.asarray([plan.slots_in_ct(i) for i in k])
+        h_off = (k * (x.shape[1] // plan.n_k) * limbs.RADIX_BITS
+                 + (used - 1 - s) * base.b_gh)
+        off = np.concatenate([h_off + base.b_h, h_off]).astype(np.int32)
+        width = np.repeat(np.int32([base.b_g, base.b_h]), l)
+        bits = limbs.by_row_blocks(
+            lambda a: encoding.fields_float64_bits(a, off, width), x,
+            limbs.ROW_BLOCK // plan.n_k)
+        f = encoding.float64_of_bits(bits)          # (2 l, M): g rows, h rows
+        return encoding.unpack_gh_floats(f[:l].T, f[l:].T, base,
+                                         np.asarray(counts)[:, None])
 
 
 # ---------------------------------------------------------------------------
@@ -832,11 +862,15 @@ def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
     """Guest side of the layer batch: ONE batched decrypt + decode
     (Algorithm 6) of the candidate stack a host answered ``assign_sync``
     with (``HostRuntime.on_assign_sync``).  In-process the stack is still
-    device-resident and the decrypt's copy to numpy synchronizes the whole
+    device-resident and waiting for its plaintexts synchronizes the whole
     in-flight cipher pipeline; over the transport it arrives as a decoded
-    limb tensor.  Returns {nid: SplitCandidates}."""
+    limb tensor.  A codec with a ``decode_device`` (MO) decodes the
+    plaintexts where they are; the others copy them to numpy in the
+    decrypt.  Returns {nid: SplitCandidates}."""
     limb = ctx.cipher.backend == "limb"
-    n_slots = ctx.codec.n_slots
+    codec = ctx.codec
+    on_device = limb and codec.decode_device is not None
+    n_slots = codec.n_slots
     tr = ctx.channel.tracer
     with tr.span("decrypt", tree=int(ctx.tree_idx),
                  nodes=len(splittable)) as dcr:
@@ -847,19 +881,27 @@ def _host_layer_finish(ctx: TreeContext, host, splittable: list) -> dict:
         if sizes is None:
             # keep a limb candidate stack on device into the decrypt
             data = data.reshape((M * n_slots, -1) if limb else M * n_slots)
-        plain = _decrypt(ctx, data)
+        plain = _decrypt(ctx, data, on_device)
         if tr.enabled:
             dcr.attrs["cts"] = len(plain)
     ctx.stats.n_decrypt += len(plain)
     with tr.span("decode", tree=int(ctx.tree_idx), ints=len(plain),
-                 **ctx.codec.span_attrs) as dec:
-        gh = ctx.codec.decode_limbs(plain, cl, sizes) if limb else None
-        path = "pyints" if gh is None else "words"
+                 **codec.span_attrs) as dec:
+        gh, path = None, "device"
+        if on_device:
+            gh = codec.decode_device(plain, cl)
+            if gh is None:      # a field too wide for the bit reader
+                plain = np.asarray(plain)
+        if gh is None and limb:
+            gh, path = codec.decode_limbs(plain, cl, sizes), "words"
         if gh is None:      # the Paillier oracle, or a field too wide
-            gh = _decode_pyints(ctx.codec, plain, cl, sizes, limb)
-            ctx.stats.n_decode_pyints += M
-        else:
+            gh, path = _decode_pyints(codec, plain, cl, sizes, limb), "pyints"
+        if path == "device":
+            ctx.stats.n_decode_device += M
+        elif path == "words":
             ctx.stats.n_decode_words += M
+        else:
+            ctx.stats.n_decode_pyints += M
         if tr.enabled:
             dec.attrs["path"] = path
         g_l, h_l = gh
@@ -884,16 +926,19 @@ def _decode_pyints(codec, plain, counts, sizes, limb: bool):
     return codec.decode(rows, counts)
 
 
-def _decrypt(ctx: TreeContext, cts):
-    """Plaintexts of a candidate stack: numpy limbs on the limb backends
-    (reading them back waits for the device work still in flight), python
-    ints on the Paillier oracle."""
+def _decrypt(ctx: TreeContext, cts, on_device: bool):
+    """Plaintexts of a candidate stack: limbs on the limb backends, numpy
+    or, ``on_device``, left on the device once ready (either waits for the
+    device work still in flight); python ints on the Paillier oracle."""
     if ctx.cipher.backend != "limb":
         return ctx.cipher.decrypt_to_ints(cts)
     import jax.numpy as jnp
+
+    def ready(plain):
+        return plain.block_until_ready() if on_device else np.asarray(plain)
     x = jnp.asarray(cts)
     if not (ctx.cipher.name == "affine" and ctx.params.use_pallas):
-        return np.asarray(ctx.cipher.decrypt_limbs(x))
+        return ready(ctx.cipher.decrypt_limbs(x))
     from ..kernels.modmul import decrypt_batch
     mesh = _crypto_mesh(ctx.params, ctx.cipher)
     n = x.shape[0]
@@ -921,8 +966,10 @@ def _decrypt(ctx: TreeContext, cts):
             x = jnp.pad(x, [(0, bucket - n)] + [(0, 0)] * (x.ndim - 1))
         x = jax.device_put(
             x, gbdt_sharding(mesh, "split_infos", ndim=x.ndim))
-        return np.asarray(decrypt_batch(ctx.cipher, x, mesh=mesh))[:n]
-    return np.asarray(limbs.by_row_blocks(
+        plain = decrypt_batch(ctx.cipher, x, mesh=mesh)
+        return plain[:n].block_until_ready() if on_device \
+            else np.asarray(plain)[:n]
+    return ready(limbs.by_row_blocks(
         lambda a: decrypt_batch(ctx.cipher, a), x))
 
 
