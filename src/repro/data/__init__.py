@@ -1,2 +1,2 @@
-from .pipeline import (PrefetchLoader, RowBlocks, SyntheticTokens,  # noqa: F401
+from .pipeline import (PrefetchLoader, RowBlocks,  # noqa: F401
                        synthetic_tabular, synthetic_tabular_stream)

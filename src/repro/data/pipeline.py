@@ -1,9 +1,5 @@
-"""Data pipelines: synthetic token streams (LM) and tabular generators
-(GBDT), with a bounded-prefetch loader for straggler isolation.
-
-The token stream is deterministic-per-step (seeded by step index) so a
-restore-and-replay after a failure reproduces the exact batch sequence --
-a requirement for bitwise-reproducible recovery."""
+"""Data pipelines: synthetic tabular generators (GBDT) and a
+bounded-prefetch loader for straggler isolation."""
 
 from __future__ import annotations
 
@@ -11,24 +7,6 @@ import queue
 import threading
 
 import numpy as np
-
-
-class SyntheticTokens:
-    """Deterministic synthetic LM batches: batch(step) is a pure function."""
-
-    def __init__(self, vocab: int, batch: int, seq: int, seed: int = 0):
-        self.vocab = vocab
-        self.batch = batch
-        self.seq = seq
-        self.seed = seed
-
-    def __call__(self, step: int) -> dict:
-        rng = np.random.default_rng((self.seed, step))
-        toks = rng.integers(0, self.vocab, (self.batch, self.seq + 1),
-                            dtype=np.int32)
-        # mildly learnable structure: next token correlates with current
-        toks[:, 1:] = (toks[:, :-1] * 31 + toks[:, 1:] % 7) % self.vocab
-        return {"tokens": toks[:, :-1], "labels": toks[:, 1:]}
 
 
 def synthetic_tabular(n: int, d: int, seed: int = 0, task: str = "binary",
@@ -56,12 +34,11 @@ class PrefetchLoader:
     finite mode ``RowBlocks`` uses for one lookahead pass over a chunked
     source."""
 
-    def __init__(self, fn, depth: int = 2, start_step: int = 0,
-                 n_steps: int | None = None):
+    def __init__(self, fn, depth: int = 2, n_steps: int | None = None):
         self.fn = fn
         self.q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
-        self._step = start_step
+        self._step = 0
         self._n_steps = n_steps
         self._thread = threading.Thread(target=self._run, daemon=True)
         self._thread.start()
@@ -79,7 +56,7 @@ class PrefetchLoader:
                 except queue.Full:
                     continue
 
-    def __call__(self, step: int) -> dict:
+    def __call__(self, step: int):
         return self.q.get()
 
     def stop(self):

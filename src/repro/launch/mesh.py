@@ -1,21 +1,12 @@
-"""Production mesh construction.
+"""Mesh construction for the GBDT engine.
 
 A FUNCTION (not module-level state) so importing never touches jax device
-initialization.  Single pod: (16, 16) = 256 chips, axes (data, model).
-Multi-pod: (2, 16, 16) = 512 chips, axes (pod, data, model) -- "pod" carries
-DP (or pipeline stages) across the slower inter-pod links.  The axis layout
-scales to N pods by changing the leading dim only.
+initialization.
 """
 
 from __future__ import annotations
 
 import jax
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_gbdt_mesh(model: int | None = None):
@@ -39,9 +30,3 @@ def make_gbdt_mesh(model: int | None = None):
     return jax.make_mesh((n // model, model), ("data", "model"),
                          axis_types=(auto, auto))
 
-
-def make_debug_mesh(*, multi_pod: bool = False):
-    """Tiny mesh for CI-sized validation of the same code paths (8 devices)."""
-    shape = (2, 2, 2) if multi_pod else (2, 4)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)

@@ -1,163 +1,23 @@
-"""Sharding rule tables: parameter/batch/cache PartitionSpecs per mesh.
+"""Sharding rule table of the GBDT engine: a PartitionSpec per array name.
 
-LM rules are keyed on the trailing path of each parameter leaf; stacked
-layer axes (from scan-over-layers) get a leading None.  "data" expands to
-("pod", "data") on the multi-pod mesh (DP across pods); "model" carries
-TP/EP.  ZeRO-1: optimizer moments additionally shard their first replicated
-axis over "data" when divisible.
-
-GBDT rules (``GBDT_RULES`` / :func:`gbdt_specs`) are keyed by array name:
-the SecureBoost+ frontier engine shards instances over "data" and the layer
-histogram's node axis over "model" (DESIGN.md §5/§7).
+``GBDT_RULES`` / :func:`gbdt_specs` are keyed by array name: the
+SecureBoost+ frontier engine shards instances over "data" and the layer
+histogram's node axis over "model" (DESIGN.md §5/§7).  "data" expands to
+("pod", "data") on a multi-pod mesh.
 """
 
 from __future__ import annotations
 
-import jax
 import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-# trailing-key -> spec for the *unstacked* param (layer axis prepended later)
-_RULES = {
-    ("embed",): ("model", None),
-    ("lm_head",): (None, "model"),
-    ("attn", "wq"): (None, "model"),
-    ("attn", "wk"): (None, "model"),
-    ("attn", "wv"): (None, "model"),
-    ("attn", "wo"): ("model", None),
-    ("xattn", "wq"): (None, "model"),
-    ("xattn", "wk"): (None, "model"),
-    ("xattn", "wv"): (None, "model"),
-    ("xattn", "wo"): ("model", None),
-    ("ffn", "wi"): (None, "model"),
-    ("ffn", "wg"): (None, "model"),
-    ("ffn", "wo"): ("model", None),
-    ("shared", "wi"): (None, "model"),
-    ("shared", "wg"): (None, "model"),
-    ("shared", "wo"): ("model", None),
-    ("moe", "router"): (None, None),
-    ("moe", "wi"): ("model", None, None),      # EP: experts over model
-    ("moe", "wg"): ("model", None, None),
-    ("moe", "wo"): ("model", None, None),
-    ("rec", "wx"): (None, "model"),
-    ("rec", "wgate"): (None, "model"),
-    ("rec", "wo"): ("model", None),
-    ("rec", "wr"): (None, "model"),
-    ("rec", "wi"): (None, "model"),
-    ("rec", "lam"): ("model",),
-    ("rec", "conv"): (None, "model"),
-    ("ssd", "in_proj"): (None, None),          # small dims: replicate
-    ("ssd", "out_proj"): (None, None),
-}
-
-
-def _data_axes(mesh):
-    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
-
-
-def _match(path_keys):
-    for k in range(2, 0, -1):
-        rule = _RULES.get(tuple(path_keys[-k:]))
-        if rule is not None:
-            return rule
-    return None
-
-
-def _leaf_spec(path, leaf, mesh):
-    keys = [getattr(p, "key", getattr(p, "name", None)) or str(p)
-            for p in path]
-    keys = [k for k in keys if isinstance(k, str)]
-    rule = _match(keys)
-    ndim = np.ndim(leaf) if not hasattr(leaf, "ndim") else leaf.ndim
-    if rule is None:
-        return P(*([None] * ndim))
-    rule = list(rule)
-    # stacked layer axis (scan): param rank exceeds the rule rank
-    while len(rule) < ndim:
-        rule = [None] + rule
-    rule = rule[:ndim]
-    # drop axes that don't divide
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    shape = leaf.shape
-    fixed = []
-    for dim, ax in zip(shape, rule):
-        if ax is not None and dim % sizes.get(ax, 1) != 0:
-            ax = None
-        fixed.append(ax)
-    return P(*fixed)
-
-
-def param_specs(params, mesh):
-    """PartitionSpec pytree for a parameter pytree."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, leaf: _leaf_spec(path, leaf, mesh), params)
-
-
-def param_shardings(params, mesh):
-    return jax.tree.map(lambda s: NamedSharding(mesh, s),
-                        param_specs(params, mesh))
-
-
-def opt_specs(params, mesh, zero1: bool = True):
-    """Moment specs: param spec + ZeRO-1 sharding of the first free axis
-    over 'data' when divisible."""
-    specs = param_specs(params, mesh)
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    dax = _data_axes(mesh)
-    d_total = int(np.prod([sizes[a] for a in dax]))
-
-    def widen(path, leaf):
-        spec = _leaf_spec(path, leaf, mesh)
-        if not zero1:
-            return spec
-        parts = list(spec)
-        for i, (dim, ax) in enumerate(zip(leaf.shape, parts)):
-            if ax is None and dim % d_total == 0:
-                parts[i] = dax if len(dax) > 1 else dax[0]
-                break
-        return P(*parts)
-
-    moments = jax.tree_util.tree_map_with_path(widen, params)
-    return moments
-
-
-def _fit(spec: P, shape, mesh) -> P:
-    """Drop spec axes whose mesh extent doesn't divide the array dim."""
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-    out = []
-    for dim, ax in zip(shape, tuple(spec) + (None,) * len(shape)):
-        if ax is None:
-            out.append(None)
-            continue
-        axes = ax if isinstance(ax, tuple) else (ax,)
-        total = int(np.prod([sizes.get(a, 1) for a in axes]))
-        out.append(ax if dim % total == 0 else None)
-    return P(*out)
-
-
-def batch_specs(batch_shapes: dict, mesh) -> dict:
-    """Tokens/labels/embeds: batch axis over data(+pod), rest replicated.
-    Non-divisible batch dims (e.g. global_batch=1 long-context cells) fall
-    back to replication."""
-    dax = _data_axes(mesh)
-    d = dax if len(dax) > 1 else dax[0]
-
-    def spec(leaf):
-        return _fit(P(*([d] + [None] * (len(leaf.shape) - 1))), leaf.shape,
-                    mesh)
-    return jax.tree.map(spec, batch_shapes)
-
-
-# ---------------------------------------------------------------------------
-# GBDT (SecureBoost+) rule table — DESIGN.md §5
-# ---------------------------------------------------------------------------
-# At-rest layouts for the frontier engine (core/frontier.py) and the launch
-# cell (launch/gbdt_cell.py).  Instances shard over "data"; the *feature*
-# axis of at-rest binned matrices carries the party boundary on "model"
-# (cross-party cell), while the per-layer histogram batch shards its *node*
-# axis over "model" — the node axis is the one that doubles with depth, so
-# it is what the intra-party frontier dispatch block-shards.
+# At-rest layouts for the frontier engine (core/frontier.py), DESIGN.md §5.
+# Instances shard over "data"; the *feature* axis of at-rest binned
+# matrices carries the party boundary on "model", while
+# the per-layer histogram batch shards its *node* axis over "model" — the
+# node axis is the one that doubles with depth, so it is what the
+# intra-party frontier dispatch block-shards.
 
 GBDT_RULES = {
     "bins": ("data", "model"),        # (instance, feature) binned matrix
@@ -189,6 +49,10 @@ GBDT_RULES = {
 }
 
 
+def _data_axes(mesh):
+    return ("pod", "data") if "pod" in mesh.axis_names else ("data",)
+
+
 def data_pad(mesh, n: int) -> int:
     """Rows to append so an instance/candidate axis of extent ``n`` divides
     the mesh's data-axis extent (device_put of a sharded layout requires
@@ -202,8 +66,7 @@ def data_pad(mesh, n: int) -> int:
 def gbdt_specs(mesh) -> dict:
     """PartitionSpec per GBDT frontier-engine array (name -> P).
 
-    "data" expands to ("pod", "data") on a multi-pod mesh, mirroring the LM
-    rule table above."""
+    "data" expands to ("pod", "data") on a multi-pod mesh."""
     dax = _data_axes(mesh)
     d = dax if len(dax) > 1 else dax[0]
     return {k: P(*[d if a == "data" else a for a in v])
@@ -228,22 +91,3 @@ def gbdt_sharding(mesh, name: str, ndim: int | None = None,
              else (d if a == "data" else a) for a in rule]
     return NamedSharding(mesh, P(*parts))
 
-
-def cache_specs(cache, mesh):
-    """KV caches: (L, B, S, kv, hd) -> batch over data, S over model
-    (split-KV decode); recurrent states: batch over data only."""
-    dax = _data_axes(mesh)
-    d = dax if len(dax) > 1 else dax[0]
-    sizes = dict(zip(mesh.axis_names, mesh.devices.shape))
-
-    def spec(leaf):
-        nd = leaf.ndim
-        if nd == 5:          # stacked KV cache (L, B, S, kv, hd)
-            s_ok = leaf.shape[2] % sizes.get("model", 1) == 0
-            p = P(None, d, "model" if s_ok else None, None, None)
-        elif nd >= 2:        # stacked recurrent state (L, B, ...)
-            p = P(*([None, d] + [None] * (nd - 2)))
-        else:
-            p = P(*([None] * nd))
-        return _fit(p, leaf.shape, mesh)
-    return jax.tree.map(spec, cache)

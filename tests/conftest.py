@@ -1,9 +1,10 @@
 """Shared test config.
 
-The suite jit-compiles hundreds of distinct programs (10 archs x variants x
-cipher widths); on a small host the accumulated XLA executables can exhaust
-memory late in the run.  Clearing JAX caches between modules bounds the
-footprint without touching test semantics.
+The suite jit-compiles hundreds of distinct GBDT programs per worker
+(kernels, ciphers and key widths, mesh layouts, objectives); on a small
+host the accumulated XLA executables can exhaust memory late in the run.
+Clearing JAX caches between modules bounds the footprint without touching
+test semantics.
 """
 
 import jax

@@ -1,5 +1,5 @@
 """Substrate tests: checkpointing (atomic/elastic), fault-tolerant loop,
-straggler policy, data pipeline, optimizer, gradient compression."""
+straggler policy, data pipeline."""
 
 import os
 
@@ -9,9 +9,7 @@ import numpy as np
 import pytest
 
 from repro import checkpoint as ck
-from repro.data import PrefetchLoader, SyntheticTokens, synthetic_tabular
-from repro.optim import AdamWConfig, adamw_update, init_adamw
-from repro.parallel.compression import compress_grads, decompress
+from repro.data import PrefetchLoader, synthetic_tabular
 from repro.runtime import ResilientLoop, StragglerError, StragglerPolicy
 
 
@@ -86,45 +84,14 @@ def test_straggler_policy_trips():
 
 
 def test_data_determinism_and_prefetch():
-    ds = SyntheticTokens(vocab=100, batch=2, seq=8, seed=3)
-    b1, b2 = ds(5), ds(5)
-    np.testing.assert_array_equal(b1["tokens"], b2["tokens"])
-    loader = PrefetchLoader(ds, depth=2)
-    out = loader(0)
-    assert out["tokens"].shape == (2, 8)
+    def source(step):
+        rng = np.random.default_rng((3, step))
+        return {"x": rng.integers(0, 100, (2, 8), dtype=np.int32)}
+    np.testing.assert_array_equal(source(5)["x"], source(5)["x"])
+    loader = PrefetchLoader(source, depth=2)
+    for step in range(3):      # batches arrive in step order
+        np.testing.assert_array_equal(loader(step)["x"], source(step)["x"])
     loader.stop()
-
-
-@pytest.mark.parametrize("quantized", [False, True])
-def test_adamw_reduces_loss(quantized):
-    cfg = AdamWConfig(lr=0.1, weight_decay=0.0, quantize_moments=quantized)
-    w = {"w": jnp.asarray([2.0, -3.0])}
-    state = init_adamw(w, cfg)
-
-    def loss(p):
-        return jnp.sum(p["w"] ** 2)
-    l0 = float(loss(w))
-    for _ in range(60):
-        g = jax.grad(loss)(w)
-        w, state = adamw_update(w, g, state, cfg)
-    assert float(loss(w)) < 0.05 * l0
-
-
-def test_grad_compression_error_feedback():
-    rng = np.random.default_rng(0)
-    g = {"a": jnp.asarray(rng.normal(0, 1, (64,)), jnp.float32)}
-    payload, err = compress_grads(g)
-    rec = decompress(payload)
-    # int8 quantization error is bounded by scale/2 per element
-    scale = float(payload["a"][1])
-    assert float(jnp.abs(rec["a"] - g["a"]).max()) <= scale
-    # error feedback: accumulated error is carried into the next round
-    payload2, err2 = compress_grads(g, err)
-    rec2 = decompress(payload2)
-    two_step = (np.asarray(rec["a"]) + np.asarray(rec2["a"])) / 2
-    direct = np.asarray(g["a"])
-    assert np.abs(two_step - direct).mean() < np.abs(
-        np.asarray(rec["a"]) - direct).mean() + 1e-6
 
 
 def test_synthetic_tabular_shapes():
