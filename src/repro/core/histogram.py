@@ -13,7 +13,8 @@ Two engines share one split-finding path:
   (resp. (n_f, n_b, n_slots)).  Binary tasks use n_slots = 1.
 
 * :class:`PlainHistogram` -- guest side (and the local-XGBoost baseline).
-  Same shapes in plaintext float64 via ``np.add.at``.
+  Same shapes in plaintext float64 via per-feature ``np.bincount`` (the
+  per-node oracle and the out-of-core path keep ``np.add.at``).
 
 Both engines additionally expose :meth:`layer_histograms`, the layer-batched
 hot path (see DESIGN.md §6): every direct-mode frontier node of one tree
@@ -53,17 +54,25 @@ GID_STRIDE = 1 << 20
 class PlainHistogram:
     """Plaintext (g, h, count) histograms: (n_f, n_b) float64 / int64.
 
-    ``row_block > 0`` makes the layer path iterate the concatenated row
-    index in contiguous chunks (out-of-core guest, DESIGN.md §13): the
-    O(rows) gather temporaries (bins / g / h / composite-index slices)
-    shrink to O(block) while every ``np.add.at`` still applies the same
-    additions to each cell in the same order — float64 accumulation is
-    sequential either way, so the result is bit-identical."""
+    The layer path accumulates direct nodes by per-feature ``np.bincount``;
+    ``node_histogram`` keeps ``np.add.at`` as the per-node oracle.  Both add
+    each cell's values in row order from 0.0, so they agree bit for bit.
 
-    def __init__(self, n_bins: int, sparse: bool = False, row_block: int = 0):
+    ``row_block > 0`` makes the layer path iterate the concatenated row
+    index in contiguous chunks with ``np.add.at`` (out-of-core guest,
+    DESIGN.md §13): the O(rows) gather temporaries (bins / g / h /
+    composite-index slices) shrink to O(block) while every ``np.add.at``
+    still applies the same additions to each cell in the same order —
+    float64 accumulation is sequential either way, so the result is
+    bit-identical.  ``stats`` (a ``party.Stats``) counts the direct nodes
+    each path accumulated."""
+
+    def __init__(self, n_bins: int, sparse: bool = False, row_block: int = 0,
+                 stats=None):
         self.n_bins = n_bins
         self.sparse = sparse
         self.row_block = row_block
+        self.stats = stats
 
     def node_histogram(self, data: BinnedData, g: np.ndarray, h: np.ndarray,
                        rows: np.ndarray):
@@ -98,9 +107,11 @@ class PlainHistogram:
         """Batched node_histogram for one tree layer.
 
         node_rows: {nid: row ids}; direct: nids accumulated directly (one
-        composite ``np.add.at`` pass per feature); subtract: (nid, parent,
-        sibling) triples resolved as parent - sibling from ``cache`` /this
-        layer's direct results.  Returns {nid: (G, H, C)}.
+        ``np.bincount`` per feature and output column over the composite
+        ``node_slot * n_bins + bin`` index, or ``np.add.at`` in row blocks
+        when ``row_block > 0``); subtract: (nid, parent, sibling) triples
+        resolved as parent - sibling from ``cache`` /this layer's direct
+        results.  Returns {nid: (G, H, C)}.
         """
         out = {}
         if direct:
@@ -115,40 +126,22 @@ class PlainHistogram:
             H = np.zeros((n_f, n_d * n_b) + out_dim)
             C = np.zeros((n_f, n_d * n_b), np.int64)
             sparse = self.sparse and data.zero_mask is not None
-            if sparse:
-                gt = np.zeros((n_d,) + out_dim)
-                ht = np.zeros((n_d,) + out_dim)
-                ct = np.zeros(n_d, np.int64)
-            R = len(rows_cat)
-            step = self.row_block if self.row_block > 0 else max(R, 1)
-            # contiguous chunks of the concatenated index: each np.add.at
-            # sees the same per-cell addition sequence as one monolithic
-            # pass, so chunking changes peak memory, not a single bit
-            for s0 in range(0, R, step):
-                rc = rows_cat[s0: s0 + step]
-                sc = slot_cat[s0: s0 + step]
-                bins = data.bins[rc]                  # (r, n_f)
-                gr, hr = g[rc], h[rc]
-                comp = sc[:, None] * n_b + bins       # composite (node, bin)
-                zmask = data.zero_mask[rc] if sparse else None
-                for f in range(n_f):
-                    if sparse:
-                        keep = ~zmask[:, f]
-                        np.add.at(G[f], comp[keep, f], gr[keep])
-                        np.add.at(H[f], comp[keep, f], hr[keep])
-                        np.add.at(C[f], comp[keep, f], 1)
-                    else:
-                        np.add.at(G[f], comp[:, f], gr)
-                        np.add.at(H[f], comp[:, f], hr)
-                        np.add.at(C[f], comp[:, f], 1)
-                if sparse:
-                    np.add.at(gt, sc, gr)
-                    np.add.at(ht, sc, hr)
-                    ct += np.bincount(sc, minlength=n_d)
+            # sparse: per-node (g, h, count) totals for the zero-bin fill-in
+            tot = ([np.zeros((n_d,) + out_dim), np.zeros((n_d,) + out_dim),
+                    np.zeros(n_d, np.int64)] if sparse else None)
+            accumulate = (self._add_at_blocks if self.row_block > 0
+                          else self._bincount)
+            accumulate(data, g, h, rows_cat, slot_cat, (G, H, C), tot)
+            if self.stats is not None:
+                if self.path == "bincount":
+                    self.stats.n_guest_hist_bincount += n_d
+                else:
+                    self.stats.n_guest_hist_add_at += n_d
             Gn = np.moveaxis(G.reshape((n_f, n_d, n_b) + out_dim), 1, 0)
             Hn = np.moveaxis(H.reshape((n_f, n_d, n_b) + out_dim), 1, 0)
             Cn = np.moveaxis(C.reshape(n_f, n_d, n_b), 1, 0)
             if sparse:
+                gt, ht, ct = tot
                 for f in range(n_f):
                     zb = int(data.zero_bins[f])
                     Gn[:, f, zb] += gt - Gn[:, f].sum(axis=1)
@@ -159,6 +152,78 @@ class PlainHistogram:
         for nid, par, sib in subtract:
             out[nid] = self.subtract(cache[par], out[sib])
         return out
+
+    @property
+    def path(self) -> str:
+        """Which accumulation the layer path runs: ``bincount`` or, for the
+        out-of-core guest, ``add_at``."""
+        return "add_at" if self.row_block > 0 else "bincount"
+
+    def _bincount(self, data, g, h, rows_cat, slot_cat, hists, tot):
+        """Direct-node accumulation by one ``np.bincount`` per feature (and
+        per output column for g and h) over a feature-major composite
+        index.  ``bincount`` adds each cell's weights in input order from
+        0.0, exactly as ``np.add.at`` does, so every cell is bit-identical.
+        The index is built contiguous as (n_f, r) intp once per layer: a
+        strided feature row would cost the loop several times over."""
+        G, H, C = hists
+        n_f, m = C.shape
+        R = len(rows_cat)
+        comp = np.empty((n_f, R), np.intp)
+        np.add(data.bins[rows_cat].T, (slot_cat * self.n_bins)[None, :],
+               out=comp)
+        gr, hr = g[rows_cat], h[rows_cat]
+        # (l, r) contiguous weights: one row per output column
+        gw = np.ascontiguousarray(gr.reshape(R, -1).T)
+        hw = np.ascontiguousarray(hr.reshape(R, -1).T)
+        G3, H3 = G.reshape(n_f, m, -1), H.reshape(n_f, m, -1)
+        zmask = (np.ascontiguousarray(data.zero_mask[rows_cat].T)
+                 if tot is not None else None)
+        for f in range(n_f):
+            idx, gf, hf = comp[f], gw, hw
+            if zmask is not None:
+                keep = ~zmask[f]
+                idx, gf, hf = idx[keep], gw[:, keep], hw[:, keep]
+            C[f] = np.bincount(idx, minlength=m)
+            for c in range(gf.shape[0]):
+                G3[f, :, c] = np.bincount(idx, gf[c], minlength=m)
+                H3[f, :, c] = np.bincount(idx, hf[c], minlength=m)
+        if tot is not None:
+            np.add.at(tot[0], slot_cat, gr)
+            np.add.at(tot[1], slot_cat, hr)
+            tot[2] += np.bincount(slot_cat, minlength=len(tot[2]))
+
+    def _add_at_blocks(self, data, g, h, rows_cat, slot_cat, hists, tot):
+        """Out-of-core accumulation: ``np.add.at`` over contiguous chunks of
+        the concatenated row index.  Each cell sees the same addition
+        sequence as one monolithic pass, so chunking changes peak memory,
+        not a single bit (adding per-chunk bincount sums would reorder the
+        additions)."""
+        G, H, C = hists
+        n_f = C.shape[0]
+        n_b = self.n_bins
+        R = len(rows_cat)
+        for s0 in range(0, R, self.row_block):
+            rc = rows_cat[s0: s0 + self.row_block]
+            sc = slot_cat[s0: s0 + self.row_block]
+            bins = data.bins[rc]                      # (r, n_f)
+            gr, hr = g[rc], h[rc]
+            comp = sc[:, None] * n_b + bins           # composite (node, bin)
+            zmask = data.zero_mask[rc] if tot is not None else None
+            for f in range(n_f):
+                if zmask is not None:
+                    keep = ~zmask[:, f]
+                    np.add.at(G[f], comp[keep, f], gr[keep])
+                    np.add.at(H[f], comp[keep, f], hr[keep])
+                    np.add.at(C[f], comp[keep, f], 1)
+                else:
+                    np.add.at(G[f], comp[:, f], gr)
+                    np.add.at(H[f], comp[:, f], hr)
+                    np.add.at(C[f], comp[:, f], 1)
+            if tot is not None:
+                np.add.at(tot[0], sc, gr)
+                np.add.at(tot[1], sc, hr)
+                tot[2] += np.bincount(sc, minlength=len(tot[2]))
 
     @staticmethod
     def subtract(parent, child):

@@ -57,6 +57,9 @@ class Stats:
                                 # device (float64 bits), ...
     n_decode_words: int = 0     # ... from limb words (numpy) ...
     n_decode_pyints: int = 0    # ... and through python ints
+    n_guest_hist_bincount: int = 0  # direct nodes the guest accumulated by
+                                    # per-feature np.bincount, ...
+    n_guest_hist_add_at: int = 0    # ... and by np.add.at row blocks
     n_hist_launches: int = 0    # histogram accumulation kernel launches
     n_split_roundtrips: int = 0  # guest<->host split_infos exchanges
     n_collectives: int = 0      # intra-party device collectives (psum)

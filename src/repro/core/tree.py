@@ -818,11 +818,12 @@ def _resolve_modes(splittable: list, hist_mode: dict, cache,
 
 def _layer_candidates(ctx: TreeContext, depth: int, plan: dict | None,
                       hosts: list, splittable: list,
-                      guest: Callable[[], dict]) -> tuple:
+                      guest: Callable[[], dict], guest_path: str) -> tuple:
     """One layer's candidate phase: every participating host gets the
     layer ``plan`` (in-process, delivering it runs the host's
     ``host_layer`` work, which leaves the cipher pipeline in flight on the
-    device), the guest computes its plaintext candidates (``guest()``)
+    device), the guest computes its plaintext candidates (``guest()``,
+    accumulated by ``guest_path``, the guest engine's ``path``)
     meanwhile, then each host's ``split_infos`` reply is decrypted and
     decoded.  The two sides are independent until ``find_best_split``
     (DESIGN.md §8).  Returns ``(guest_cands, host_cands)``."""
@@ -842,6 +843,8 @@ def _layer_candidates(ctx: TreeContext, depth: int, plan: dict | None,
         sync_plan()
         with tr.timed("guest_hist", **tkw) as gh:
             guest_cands = guest()
+            if tr.enabled and guest_cands:
+                gh.attrs["path"] = guest_path
         with tr.timed("decrypt_wait", **tkw) as wait:
             for h in hosts:
                 ctx.stats.n_split_roundtrips += 1
@@ -977,7 +980,7 @@ def _guest_layer_candidates(ctx: TreeContext, guest_frontier: GuestFrontier,
                             splittable: list, rows_sel: dict,
                             hist_mode: dict) -> dict:
     """Guest-side plaintext mirror of the layer batch: one composite
-    ``np.add.at`` pass for all direct nodes, subtraction for the rest."""
+    ``np.bincount`` pass for all direct nodes, subtraction for the rest."""
     direct, subtract = _resolve_modes(splittable, hist_mode, guest_frontier,
                                       ctx.params.histogram_subtraction)
     node_rows = {nid: ctx.sel_rows[rows_sel[nid]] for nid in splittable}
@@ -1032,7 +1035,8 @@ def _grow_tree(ctx: TreeContext,
             _encrypt_all(ctx, g_sel, h_sel)
 
     plain_engine = PlainHistogram(p.n_bins, sparse=p.sparse,
-                                 row_block=getattr(p, "row_block", 0))
+                                 row_block=getattr(p, "row_block", 0),
+                                 stats=ctx.stats)
     guest_frontier = GuestFrontier(plain_engine, ctx.guest_data, ctx.g, ctx.h)
 
     n_all = ctx.guest_data.n_instances
@@ -1107,7 +1111,8 @@ def _grow_tree(ctx: TreeContext,
             return {}
 
         guest_cands, host_cands = _layer_candidates(
-            ctx, depth, plan, active_hosts, splittable, guest)
+            ctx, depth, plan, active_hosts, splittable, guest,
+            plain_engine.path)
 
         with ctx.channel.tracer.span("split", tree=int(ctx.tree_idx),
                                      depth=int(depth),
@@ -1250,7 +1255,8 @@ def grow_forest(ctx: TreeContext, bags: list,
             _encrypt_all(ctx, g_sel, h_sel)
 
     plain_engine = PlainHistogram(p.n_bins, sparse=p.sparse,
-                                 row_block=getattr(p, "row_block", 0))
+                                 row_block=getattr(p, "row_block", 0),
+                                 stats=ctx.stats)
     guest_frontier = GuestFrontier(plain_engine, ctx.guest_data, ctx.g, ctx.h)
 
     n_all = ctx.guest_data.n_instances
@@ -1317,7 +1323,8 @@ def grow_forest(ctx: TreeContext, bags: list,
             return {}
 
         guest_cands, host_cands = _layer_candidates(
-            ctx, depth, plan, active_hosts, splittable, guest)
+            ctx, depth, plan, active_hosts, splittable, guest,
+            plain_engine.path)
 
         with ctx.channel.tracer.span("split", tree=int(ctx.tree_idx),
                                      depth=int(depth),
